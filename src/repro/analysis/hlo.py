@@ -55,13 +55,14 @@ _PACKED_DTYPES = ("u8", "s8", "u4", "s4", "f8e4m3fn", "f8e5m2", "f8e4m3",
 _FLOAT_DTYPES = ("f16", "bf16", "f32", "f64")
 
 _COMP_RE = re.compile(r"^(ENTRY\s+)?%?([\w\.\-]+)\s*\((.*?)\)\s*->\s*(.+?)\s*\{\s*$")
-_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w\.\-]+)\s*=\s*((?:\([^)]*\)|[\w\[\],\{\}]+))\s+([\w\-]+)\((.*)$")
+_INSTR_HEAD_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w\.\-]+)\s*=\s*")
+_OP_RE = re.compile(r"\s+([\w\-]+)\((.*)$")
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _TRIP_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
 _CALL_RE = re.compile(r"(?:calls|to_apply|condition|body)=%?([\w\.\-]+)")
 _OPERANDS_RE = re.compile(r"%([\w\.\-]+)")
 _CDIMS_RE = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
-_PARAM_RE = re.compile(r"%?([\w\.\-]+):\s*((?:\([^)]*\)|[\w\[\],\{\}]+))")
+_PARAM_RE = re.compile(r"%?([\w\.\-]+):\s*((?:\([^)]*\)|[\w\[\],\{\}:]+))")
 # one alias entry on the HloModule header line:
 #   {output_index}: (param_number, {param_index}, may-alias|must-alias)
 # the kind literal disambiguates entries, so no balanced-brace scan needed
@@ -107,6 +108,30 @@ class Computation:
     instrs: List[Instr]
 
 
+def _match_instr(raw: str) -> Optional[Tuple[str, str, str, str]]:
+    """``(name, type, op, rest)`` of one instruction line, else None. The
+    type is scanned, not matched: TPU layouts carry tiling annotations
+    (``bf16[4,5120]{1,0:T(4,128)(2,1)S(1)}``), so a tuple type nests
+    parentheses."""
+    m = _INSTR_HEAD_RE.match(raw)
+    if m is None:
+        return None
+    rest = raw[m.end():]
+    if rest.startswith("("):
+        depth = 0
+        for end, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        end += 1
+    else:
+        end = len(rest.split(None, 1)[0]) if rest.strip() else 0
+    om = _OP_RE.match(rest, end)
+    if not end or om is None:
+        return None
+    return m.group(1), rest[:end], om.group(1), om.group(2)
+
+
 def parse_computations(text: str) -> Tuple[Dict[str, Computation], Optional[str]]:
     comps: Dict[str, Computation] = {}
     entry = None
@@ -128,9 +153,9 @@ def parse_computations(text: str) -> Tuple[Dict[str, Computation], Optional[str]
         if raw.strip() == "}":
             cur = None
             continue
-        im = _INSTR_RE.match(raw)
+        im = _match_instr(raw)
         if im:
-            cur.instrs.append(Instr(*im.groups()))
+            cur.instrs.append(Instr(*im))
     return comps, entry
 
 

@@ -34,33 +34,12 @@ from jax import lax
 #: Values of the 8 non-negative FP4 E2M1 codes (code = s<<3 | e<<1 | m).
 FP4_VALUES = np.array([0.0, 0.5, 1.0, 1.5, 2.0, 3.0, 4.0, 6.0], dtype=np.float32)
 FP4_MAX = 6.0
-#: Midpoints between adjacent positive values; ties round to even code
-#: (matches RNE used by ml_dtypes' float4_e2m1fn cast).
-_FP4_MIDPOINTS = (FP4_VALUES[1:] + FP4_VALUES[:-1]) / 2.0  # 7 midpoints
-
-#: jax only exposes the float4_e2m1fn dtype from 0.4.39; older runtimes use
-#: the pure-jnp grid rounding below (bit-identical, verified in tests).
-HAS_NATIVE_FP4 = hasattr(jnp, "float4_e2m1fn")
 
 
 def fp4_round(x: jax.Array) -> jax.Array:
-    """Round f32 values onto the FP4 E2M1 value grid (RNE, saturating).
-
-    Equivalent to ``x.astype(float4_e2m1fn).astype(float32)`` — used as the
-    fallback when the runtime lacks the native dtype. All midpoints are
-    exactly representable in f32, so the tie test is exact.
-    """
-    if HAS_NATIVE_FP4:
-        return x.astype(jnp.float4_e2m1fn).astype(jnp.float32)
-    xf = x.astype(jnp.float32)
-    mag = jnp.abs(xf)
-    mid = jnp.asarray(_FP4_MIDPOINTS)
-    lo = jnp.searchsorted(mid, mag, side="left")   # ties -> lower value index
-    hi = jnp.searchsorted(mid, mag, side="right")  # ties -> upper value index
-    idx = jnp.where(lo % 2 == 0, lo, hi)           # tie: pick even mantissa code
-    mag4 = jnp.asarray(FP4_VALUES)[jnp.minimum(idx, 7)]
-    out = jnp.where(jnp.signbit(xf), -mag4, mag4)
-    return jnp.where(jnp.isnan(xf), xf, out)       # propagate NaN like the native cast
+    """Round f32 values onto the FP4 E2M1 value grid (RNE, saturating) via
+    the native ``float4_e2m1fn`` cast, which lowers for CPU and TPU alike."""
+    return x.astype(jnp.float4_e2m1fn).astype(jnp.float32)
 
 
 def fp4_encode(x: jax.Array) -> jax.Array:

@@ -154,6 +154,16 @@ def named_shardings(mesh, specs_tree: Any):
                         is_leaf=lambda x: isinstance(x, P))
 
 
+def serving_param_shardings(params_tree: Any, mesh, policy: str = "cascade",
+                            tied_embed: bool = False):
+    """NamedSharding tree that places serving params (arrays or SDS leaves)
+    on ``mesh`` under ``policy`` — the placement ``ServeEngine`` serves
+    from, and the ``out_shardings`` that initialize params already sharded."""
+    specs = filter_divisible(
+        param_specs(params_tree, policy, tied_embed=tied_embed), params_tree, mesh)
+    return named_shardings(mesh, specs)
+
+
 def batch_specs(batch_tree: Any, batch_axes=("pod", "data"), mesh=None):
     """Shard the leading batch dim of every input over the data axes (and the
     M-RoPE position stream's axis 1). Falls back to replication when the

@@ -42,11 +42,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU compiler params are optional off-TPU
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _decode_fp4_block(codes: jax.Array, dtype) -> jax.Array:
@@ -67,9 +63,10 @@ def _kernel(x_ref, wq_ref, s_ref, b_ref, o_ref, acc_ref, *, nk: int, out_dtype,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    packed = wq_ref[...]  # (bk//2, bn) uint8
-    lo = packed & jnp.uint8(0xF)
-    hi = (packed >> 4) & jnp.uint8(0xF)
+    # widen before shifting: Mosaic has no vector shift on 8-bit lanes
+    packed = wq_ref[...].astype(jnp.int32)  # (bk//2, bn) byte values
+    lo = packed & 0xF
+    hi = (packed >> 4) & 0xF
     bk2, bn = packed.shape
     codes = jnp.stack([lo, hi], axis=1).reshape(bk2 * 2, bn)
     if exact_dequant:
@@ -114,7 +111,8 @@ def cascade_matmul_pallas(
     Returns (M, N) out_dtype.
 
     ``scales``: fast mode takes (G, N) f32 with group_size = K // G and
-    group_size % block_k == 0; exact mode (``exact_dequant=True``) takes
+    group_size % block_k == 0 (laid out as (G, 1, N) so each K-block reads
+    one (1, block_n) row that meets the (8, 128) block rule for any G); exact mode (``exact_dequant=True``) takes
     per-row (K, N) f32 (pre-expanded by the caller) so the weight tile is
     dequantized exactly like ``quant.dequantize_weight`` before the dot.
     ``has_bias=False`` skips the bias add entirely (matching the jnp path's
@@ -133,8 +131,10 @@ def cascade_matmul_pallas(
         group_size = kdim // scales.shape[0]
         assert group_size % block_k == 0, (
             f"group_size {group_size} must be a multiple of block_k {block_k}")
+        scales = scales.reshape(scales.shape[0], 1, n)
         scale_spec = pl.BlockSpec(
-            (1, block_n), lambda i, j, k, gs=group_size, bk=block_k: (k * bk // gs, j))
+            (None, 1, block_n),
+            lambda i, j, k, gs=group_size, bk=block_k: (k * bk // gs, 0, j))
     nk = kdim // block_k
 
     grid = (m // block_m, n // block_n, nk)
@@ -142,12 +142,6 @@ def cascade_matmul_pallas(
     kernel = functools.partial(_kernel, nk=nk, out_dtype=out_dtype,
                                compute_dtype=compute_dtype,
                                exact_dequant=exact_dequant, has_bias=has_bias)
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        params_cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-        kwargs["compiler_params"] = params_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -159,7 +153,8 @@ def cascade_matmul_pallas(
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)] if pltpu is not None else [],
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(x, packed, scales, bias)

@@ -24,11 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -97,12 +93,6 @@ def flash_attention_pallas(
 
     kernel = functools.partial(
         _kernel, scale=scale, causal=causal, nk=nk, block_q=block_q, block_k=block_k)
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        params_cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-        kwargs["compiler_params"] = params_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
     out = pl.pallas_call(
         kernel,
         grid=(b * hq, nq, nk),
@@ -117,9 +107,10 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
-        ] if pltpu is not None else [],
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(qf, kf, vf)
     return out.reshape(b, hq, s, d)
 
@@ -148,7 +139,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, mk_ref, o_ref, mx_ref, l_ref, acc_ref,
     q = q_ref[0].astype(jnp.float32)            # (1, d)
     k = k_ref[0].astype(jnp.float32)            # (bt, d)
     v = v_ref[0].astype(jnp.float32)
-    live = mk_ref[...] != 0                     # (1, bt)
+    live = mk_ref[0] != 0                       # (1, bt)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (1, bt)
     s = jnp.where(live, s, _NEG_INF)
 
@@ -225,17 +216,14 @@ def decode_attention_pallas(
     qf = q.reshape(b * hq, 1, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * hkv, t, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * hkv, t, d)
-    mk = mask.astype(jnp.int32)
+    # (B, 1, T): a (1, block_t) mask block then spans the full second-minor
+    # dim, which the (8, 128) block rule accepts for any B
+    mk = mask.astype(jnp.int32).reshape(b, 1, t)
 
     def kv_index(bh, it):
         return ((bh // hq) * hkv + (bh % hq) // group, it, 0)
 
     kernel = functools.partial(_decode_kernel, scale=scale, nt=nt)
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        params_cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-        kwargs["compiler_params"] = params_cls(
-            dimension_semantics=("parallel", "arbitrary"))
 
     out = pl.pallas_call(
         kernel,
@@ -244,7 +232,7 @@ def decode_attention_pallas(
             pl.BlockSpec((1, 1, d), lambda bh, it: (bh, 0, 0)),
             pl.BlockSpec((1, block_t, d), kv_index),
             pl.BlockSpec((1, block_t, d), kv_index),
-            pl.BlockSpec((1, block_t), lambda bh, it: (bh // hq, it)),
+            pl.BlockSpec((1, 1, block_t), lambda bh, it: (bh // hq, 0, it)),
         ],
         out_specs=pl.BlockSpec((1, 1, d), lambda bh, it: (bh, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hq, 1, d), jnp.float32),
@@ -252,8 +240,9 @@ def decode_attention_pallas(
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
-        ] if pltpu is not None else [],
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(qf, kf, vf, mk)
     return out.reshape(b, hq, d)

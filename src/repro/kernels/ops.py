@@ -18,6 +18,23 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def fit_block(dim: int, cap: int) -> tuple[int, int]:
+    """``(padded_dim, block)`` for one blocked kernel dimension.
+
+    A dim that fits in ``cap`` is one block (a block equal to the whole
+    array dim always meets Mosaic's (8, 128) rule). A larger dim is padded
+    up to a multiple of ``align = min(128, cap)`` and gets the largest
+    multiple of ``align`` that divides the padded dim and is at most
+    ``cap`` — e.g. 13440 = 105 * 128 gives 384 at cap 512 — so the VMEM
+    footprint is bounded by the cap for every dimension."""
+    if dim <= cap:
+        return dim, dim
+    align = min(128, cap)
+    padded = -(-dim // align) * align
+    block = max(b for b in range(align, cap + 1, align) if padded % b == 0)
+    return padded, block
+
+
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     size = x.shape[axis]
     rem = (-size) % mult
@@ -48,10 +65,13 @@ def cascade_matmul(
 ) -> jax.Array:
     """FP4-packed weight matmul: x (.., K) @ Wq (K, N) -> (.., N).
 
-    Leading dims of x are flattened to M and padded to block_m; K and N must
-    already be block-aligned (true for every assigned architecture dim).
-    Odd-K weights (quantize_weight zero-row pad-to-pack) are handled by
-    padding the activations with a matching zero column.
+    Leading dims of x are flattened to M and padded to the M block (at
+    most ``block_m``, at least M rounded up to 8). N and K get their blocks
+    from :func:`fit_block` with caps ``block_n`` / ``block_k`` (K blocks
+    must also divide the scale group); an unaligned N or per-column-scale K
+    is zero-padded, never blocked whole. Odd-K weights (quantize_weight
+    zero-row pad-to-pack) are handled by padding the activations with a
+    matching zero column.
 
     ``exact_dequant`` defaults to the resolved ``interpret`` value: compiled
     (TPU) runs the fast bf16-MXU kernel; interpret mode (CPU/CI) runs a
@@ -89,17 +109,32 @@ def cascade_matmul(
             exact_dequant=True, has_bias=bias is not None, interpret=True,
         )
         return out.reshape(*lead, n)
-    x2 = _pad_to(x2, 0, block_m)
-    # shrink blocks if dims are small (smoke configs)
-    bm = min(block_m, x2.shape[0])
-    bn = block_n if n % block_n == 0 else n
-    bk = block_k if (kdim % block_k == 0 and (kdim // scales.shape[0]) % block_k == 0) else kdim // scales.shape[0]
+    bm = min(block_m, -(-m // 8) * 8)
+    x2 = _pad_to(x2, 0, bm)
+    n_pad, bn = fit_block(n, block_n)
+    groups = scales.shape[0]
+    if groups == 1:
+        k_pad, bk = fit_block(kdim, block_k)
+    else:
+        group = kdim // groups
+        group_pad, bk = fit_block(group, block_k)
+        if group_pad != group:
+            raise ValueError(f"group size {group} has no block that is a "
+                             f"multiple of {min(128, block_k)}")
+        k_pad = kdim
+    if k_pad > kdim:   # zero codes decode to 0: padded rows add nothing
+        x2 = _pad_to(x2, 1, k_pad)
+        packed = _pad_to(packed, 0, k_pad // 2)
+    if n_pad > n:
+        packed = _pad_to(packed, 1, n_pad)
+        scales = _pad_to(scales, 1, n_pad)
+        bias2 = _pad_to(bias2, 1, n_pad)
     out = _cm.cascade_matmul_pallas(
         x2, packed, scales, bias2,
         block_m=bm, block_n=bn, block_k=bk,
         out_dtype=out_dtype, interpret=interpret,
     )
-    return out[:m].reshape(*lead, n)
+    return out[:m, :n].reshape(*lead, n)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
@@ -142,7 +177,7 @@ def decode_attention(
     if interpret is None:
         interpret = not _on_tpu()
     return _fa.decode_attention_pallas(
-        q, k, v, valid, scale=scale, block_t=block_t,
+        q, k, v, valid, scale=scale, block_t=fit_block(k.shape[1], block_t)[1],
         exact=interpret, interpret=interpret)
 
 

@@ -211,8 +211,6 @@ def lower_cell(arch_id: str, shape_name: str, mesh, ccfg=None,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax<=0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     coll = collective_stats(compiled.as_text())
     record = {
         "arch": arch_id,

@@ -12,14 +12,11 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where the runtime has
-    them (jax >= 0.5 exposes jax.sharding.AxisType; older releases only
-    build Auto meshes, so the kwarg is simply dropped)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = ({"axis_types": (axis_type.Auto,) * len(axes)}
-              if axis_type is not None else {})
-    return jax.make_mesh(shape, axes, **kwargs)
+def make_mesh(shape, axes, devices=None):
+    """jax.make_mesh with every axis Auto (GSPMD-partitioned), over
+    ``devices`` when given (e.g. a described TPU topology's devices)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

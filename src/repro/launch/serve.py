@@ -27,7 +27,6 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import cascade
 from repro.core.cascade import CascadeConfig
 from repro.models import registry
 from repro.serve.engine import Request, ServeConfig, ServeEngine
@@ -109,20 +108,21 @@ def main():
     args = ap.parse_args()
 
     from repro.launch import mesh as meshlib
+    from repro.launch.compile_cache import enable_compile_cache
     if args.host_devices:
         meshlib.force_host_device_count(args.host_devices)
+    enable_compile_cache()
 
     import jax
     mesh = meshlib.make_serving_mesh(args.mesh) if args.mesh else None
     cfg, model = registry.load(args.arch, smoke=args.smoke)
     compute = jnp.float32 if jax.default_backend() == "cpu" else jnp.bfloat16
-    train_ccfg = CascadeConfig(mode="train", compute_dtype=compute)
-    params = model.init_params(jax.random.PRNGKey(0), train_ccfg)
-    if args.no_fp4:
-        ccfg = train_ccfg
-    else:
-        ccfg = CascadeConfig(mode="serve_fp4", compute_dtype=compute)
-        params = cascade.tree_to_serve_fp4(params, ccfg)
+    # random weights drawn on the device straight into the served format
+    # (packed FP4 unless --no-fp4), already placed on the mesh if any
+    ccfg = CascadeConfig(mode="train" if args.no_fp4 else "serve_fp4",
+                         compute_dtype=compute)
+    params = registry.init_params(model, ccfg, seed=0, mesh=mesh,
+                                  tp_policy=args.tp_policy)
 
     scfg = ServeConfig(max_batch=args.max_batch,
                        max_len=args.prompt_len + args.max_new + 1,

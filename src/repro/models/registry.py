@@ -69,3 +69,25 @@ def build_model(cfg: ArchConfig):
 def load(arch_id: str, smoke: bool = False):
     cfg = get_config(arch_id, smoke)
     return cfg, build_model(cfg)
+
+
+def init_params(model, ccfg, seed: int = 0, mesh=None,
+                tp_policy: str = "cascade"):
+    """Random params drawn on the device directly in ``ccfg``'s format.
+
+    One jitted ``model.init_params``: with ``ccfg.mode == "serve_fp4"`` every
+    linear is quantized as it is drawn, so no dense tree is ever held. With
+    ``mesh``, the outputs come out already placed as ``ServeEngine`` serves
+    them under ``tp_policy``: each device draws only its own shards
+    (partitionable threefry), and no device holds the whole tree."""
+    import jax
+
+    key = jax.random.PRNGKey(seed)
+    fn = lambda k: model.init_params(k, ccfg)
+    shardings = None
+    if mesh is not None:
+        from repro.distributed.sharding import serving_param_shardings
+        tied = bool(getattr(model.cfg, "tie_embeddings", False))
+        shardings = serving_param_shardings(jax.eval_shape(fn, key), mesh,
+                                            tp_policy, tied_embed=tied)
+    return jax.jit(fn, out_shardings=shardings)(key)

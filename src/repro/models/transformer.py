@@ -68,7 +68,9 @@ class TransformerLM(StackedCacheMixin):
         cfg = self.cfg
         keys = jax.random.split(key, cfg.n_layers + 2)
         params = {
-            "layers": jax.vmap(lambda k: self._layer_init(k, ccfg))(keys[: cfg.n_layers]),
+            # one layer at a time: a vmap would hold every layer's f32 draw
+            # at once (32 x 4096 x 13440 x 4 B = 7 GB for one codeqwen leaf)
+            "layers": jax.lax.map(lambda k: self._layer_init(k, ccfg), keys[: cfg.n_layers]),
             "final_norm": L.norm_init(cfg.d_model, cfg.norm_type),
         }
         if not cfg.input_embeds:

@@ -567,10 +567,8 @@ class ServeEngine:
                 # sharded dim); stacked cache shards its slot axis over data
                 tied = bool(getattr(getattr(model, "cfg", None),
                                     "tie_embeddings", False))
-                pspecs = shd.filter_divisible(
-                    shd.param_specs(params, scfg.tp_policy, tied_embed=tied),
-                    params, mesh)
-                self.params = jax.device_put(params, shd.named_shardings(mesh, pspecs))
+                self.params = jax.device_put(params, shd.serving_param_shardings(
+                    params, mesh, scfg.tp_policy, tied_embed=tied))
                 self._cache_pspecs = model.cache_pspecs(self.cache, mesh)
                 self.cache = jax.device_put(
                     self.cache, shd.named_shardings(mesh, self._cache_pspecs))
@@ -1228,6 +1226,14 @@ class ServeEngine:
         with self._sharded_scope():
             args = self._step_example_args(name)
             return entry["fn"].lower(*args).compile()
+
+    def call_step(self, name: str, *args):
+        """Dispatch one named step closure on caller-supplied arguments under
+        the engine's mesh scope — for checks that drive a step with fixed
+        inputs (teacher forcing) outside the scheduler. The closure traces
+        exactly as ``step()`` would, so its compiled program is shared."""
+        with self._sharded_scope():
+            return self.step_closures()[name]["fn"](*args)
 
     def decode_step_hlo(self, which: str = "decode") -> str:
         """Compiled HLO of a batched serving step against the live params/

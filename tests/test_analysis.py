@@ -249,19 +249,41 @@ ENTRY %main (p0: f32[8]) -> f32[8] {
 }
 """
 
+# the same module as a TPU compile prints it: every type carries a tiled
+# layout, whose parentheses and colons the instruction parser must skip
+_L = "{1,0:T(4,128)(2,1)}"
+_ASYNC_AR_HLO_TPU = f"""\
+HloModule m
 
-def test_async_allreduce_start_done_counted_once():
+%add_comb (a: bf16[], b: bf16[]) -> bf16[] {{
+  %a = bf16[] parameter(0)
+  %b = bf16[] parameter(1)
+  ROOT %add.1 = bf16[] add(bf16[] %a, bf16[] %b)
+}}
+
+ENTRY %main (p0: bf16[4,5120]{_L}) -> bf16[4,5120]{_L} {{
+  %p0 = bf16[4,5120]{_L} parameter(0)
+  %ars = bf16[4,5120]{_L} all-reduce-start(bf16[4,5120]{_L} %p0), to_apply=%add_comb
+  ROOT %ard = bf16[4,5120]{_L} all-reduce-done(bf16[4,5120]{_L} %ars)
+}}
+"""
+
+
+@pytest.mark.parametrize("text,nbytes", [(_ASYNC_AR_HLO, 32),
+                                         (_ASYNC_AR_HLO_TPU, 4 * 5120 * 2)],
+                         ids=["plain", "tpu_layout"])
+def test_async_allreduce_start_done_counted_once(text, nbytes):
     """An async all-reduce appears as a -start/-done PAIR; both the
     loop-corrected census and the partial-sum gate must count the pair as
     ONE collective (the -start carries the combiner; double-counting would
     fail budgets that are actually met)."""
     from repro.analysis import hlo
 
-    r = hlo.analyze(_ASYNC_AR_HLO)
+    r = hlo.analyze(text)
     assert r["collectives"]["all-reduce"]["count"] == 1
-    assert r["collectives"]["all-reduce"]["bytes"] == 32  # f32[8], once
-    ps = hlo.partial_sum_allreduces(_ASYNC_AR_HLO)
-    assert ps["count"] == 1 and ps["bytes"] == 32
+    assert r["collectives"]["all-reduce"]["bytes"] == nbytes  # once
+    ps = hlo.partial_sum_allreduces(text)
+    assert ps["count"] == 1 and ps["bytes"] == nbytes
 
 
 def test_variadic_tuple_combiner_is_partial_sum():

@@ -43,6 +43,34 @@ def test_cascade_matmul_batched_leading_dims():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-2, rtol=3e-2)
 
 
+@pytest.mark.parametrize("dim,cap,want", [
+    (13440, 512, (13440, 384)),    # codeqwen d_ff as K: 105 * 128
+    (13440, 256, (13440, 128)),    # ... as N
+    (92416, 256, (92416, 256)),
+    (4096, 512, (4096, 512)),
+    (200, 128, (256, 128)),        # unaligned: padded, never blocked whole
+    (64, 256, (64, 64)),           # fits one block: the whole (small) dim
+    (48, 16, (48, 16)),
+])
+def test_fit_block_is_aligned_and_bounded(dim, cap, want):
+    assert ops.fit_block(dim, cap) == want
+
+
+@pytest.mark.parametrize("m,k,n", [(5, 300, 200), (24, 640, 384)])
+def test_cascade_matmul_pads_unaligned_dims(m, k, n):
+    """K and N that no aligned block divides are zero-padded (per-column
+    scales), not blocked whole; the padding contributes nothing."""
+    w = jax.random.normal(jax.random.PRNGKey(k), (k, n)) * 0.1
+    packed, scales = quant.quantize_weight(w)
+    x = jax.random.normal(jax.random.PRNGKey(1), (m, k)) * 0.5
+    bias = jax.random.normal(jax.random.PRNGKey(2), (n,))
+    out = ops.cascade_matmul(x, packed, scales, bias, block_n=128, block_k=128,
+                             interpret=True, exact_dequant=False)
+    ref = ops.cascade_matmul_ref(x, packed, scales, bias)
+    assert out.shape == (m, n)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-2, rtol=3e-2)
+
+
 def test_cascade_matmul_groupwise_scales():
     k, n = 128, 32
     w = jax.random.normal(jax.random.PRNGKey(3), (k, n)) * 0.1

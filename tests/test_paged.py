@@ -19,7 +19,7 @@ jax.config.update("jax_platform_name", "cpu")
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.cascade import CascadeConfig
 from repro.models import registry

@@ -8,7 +8,7 @@ their configured bounds — and the VirtualClock's monotonicity.
 """
 import numpy as np
 import pytest
-from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.serve.traffic import (Clock, MonotonicClock, TrafficConfig,
                                  VirtualClock, poisson_trace)
