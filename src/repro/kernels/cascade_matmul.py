@@ -157,4 +157,5 @@ def cascade_matmul_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="cascade_matmul",
     )(x, packed, scales, bias)

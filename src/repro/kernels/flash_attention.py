@@ -111,6 +111,7 @@ def flash_attention_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(b, hq, s, d)
 
@@ -203,6 +204,7 @@ def decode_attention_pallas(
             functools.partial(_decode_exact_kernel, scale=scale, hkv=hkv),
             out_shape=jax.ShapeDtypeStruct((b, hq, d), jnp.float32),
             interpret=interpret,
+            name="decode_attention_exact",
         )(q, k, v, mask.astype(jnp.int32))
     block_t = min(block_t, t)
     rem = (-t) % block_t
@@ -244,5 +246,6 @@ def decode_attention_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attention",
     )(qf, kf, vf, mk)
     return out.reshape(b, hq, d)

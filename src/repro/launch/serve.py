@@ -29,7 +29,26 @@ import numpy as np
 
 from repro.core.cascade import CascadeConfig
 from repro.models import registry
+from repro.serve import telemetry as T
 from repro.serve.engine import Request, ServeConfig, ServeEngine
+
+
+def phase_summary(m: dict) -> str:
+    """One line of an engine's phase counters (``metrics()["phases"]``):
+    the share of step time spent in admission, the host's wait for each
+    decode step's picks, the step time outside the host's waits for the
+    device (decode and first-token syncs), and compilations."""
+    ph = m["phases"]
+
+    def secs(name):
+        return ph.get(name, {}).get("seconds", 0.0)
+
+    steps = max(ph.get(T.STEP, {}).get("calls", 0), 1)
+    step_s = max(secs(T.STEP), 1e-12)
+    host_s = step_s - secs(T.DECODE_SYNC) - secs(T.FIRST_TOKEN)
+    return (f"phases: admission {100 * secs(T.ADMIT) / step_s:.1f}% of step "
+            f"time, decode sync {1e3 * secs(T.DECODE_SYNC) / steps:.2f} ms/step, "
+            f"host {1e3 * host_s / steps:.2f} ms/step, compiles {m['compiles']}")
 
 
 def main():
@@ -255,6 +274,7 @@ def main():
     print(f"served {args.requests} requests, {total} tokens in {dt:.2f}s "
           f"({total / max(dt, 1e-9):.1f} tok/s), p99 step {eng.straggler_p99()*1e3:.1f} ms, "
           f"batched={m['batched']}{spec}{mstr}, admission wait {m['admission_wait_s_mean']*1e3:.1f} ms")
+    print("  " + phase_summary(m))
     if m["paged"]:
         print(f"  prefix cache: hit rate {m['prefix_hit_rate']:.2f} "
               f"({m['prefix_hits']}/{m['prefix_lookups']} tokens), "

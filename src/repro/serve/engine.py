@@ -104,6 +104,7 @@ from repro.core import crest
 from repro.core.cascade import CascadeConfig
 from repro.distributed import sharding as shd
 from repro.models.cache_utils import reset_slot_pos
+from repro.serve import telemetry
 from repro.serve.prefix import PagePool, RadixPrefixCache
 from repro.serve.spec import ngram_propose
 from repro.serve.traffic import MonotonicClock
@@ -333,6 +334,8 @@ class ServeEngine:
         # shared VirtualClock in deterministic traffic tests (the harness
         # advances it; the engine only reads it)
         self.clock = clock if clock is not None else MonotonicClock()
+        # spans and counters of the engine's phases, on the same clock
+        self.telemetry = telemetry.Telemetry(self.clock)
         self.tp_policy = scfg.tp_policy
         # the cascade policy installs the activation-broadcast discipline
         # (constrain_* hooks in model code); megatron is the measured GSPMD
@@ -386,9 +389,11 @@ class ServeEngine:
         self._sample_key = jax.random.PRNGKey(scfg.sample_seed)
         self._sample_step = 0
         if scfg.temperature > 0.0:
-            self._pick_fn = jax.jit(
-                lambda row, key: _sample_tokens(row[None, :], key,
-                                                scfg.temperature, scfg.top_k)[0])
+            def _pick_token(row, key):
+                return _sample_tokens(row[None, :], key, scfg.temperature,
+                                      scfg.top_k)[0]
+
+            self._pick_fn = jax.jit(_pick_token)
         self._accepted_drafts = 0     # drafted tokens the verify pass accepted
         self._spec_slot_steps = 0     # (slot, step) pairs that ran speculation
         # per-slot draft context, appended incrementally as tokens commit
@@ -533,16 +538,18 @@ class ServeEngine:
                     [] for _ in range(scfg.max_batch)]
                 self._watermark_pages = max(
                     1, int(scfg.evict_watermark * (npages - 1)))
-                self._copy_fn = jax.jit(
-                    lambda c_, s_, d_: model.paged_copy_page(c_, s_, d_),
-                    donate_argnums=(0,))
-                # a fresh lambda per engine (like _copy_fn above): jitting
-                # the bare module-level function would share one tracing
-                # cache across engines, breaking per-engine donation and
-                # the retrace guard's compile accounting
-                self._reset_pos_fn = jax.jit(
-                    lambda c_, s_, p_: reset_slot_pos(c_, s_, p_),
-                    donate_argnums=(0,))
+                def _copy_page(c_, s_, d_):
+                    return model.paged_copy_page(c_, s_, d_)
+
+                # a fresh function per engine (like _copy_page above):
+                # jitting the bare module-level function would share one
+                # tracing cache across engines, breaking per-engine donation
+                # and the retrace guard's compile accounting
+                def _reset_pos(c_, s_, p_):
+                    return reset_slot_pos(c_, s_, p_)
+
+                self._copy_fn = jax.jit(_copy_page, donate_argnums=(0,))
+                self._reset_pos_fn = jax.jit(_reset_pos, donate_argnums=(0,))
                 if scfg.prefix_cache:
                     self.prefix = RadixPrefixCache(self.pool, ps,
                                                    copy_page=self._cow_page)
@@ -592,25 +599,25 @@ class ServeEngine:
                         p, {"tokens": t, "block_table": bt}, c_, ccfg)
                     return logits, pin(c2)
 
-                self._decode_fn = jax.jit(_decode_step, donate_argnums=(2,))
-                self._extend_fn = jax.jit(
-                    lambda p, t, c_, bt, n: model.prefill_extend(
+                def _extend_step(p, t, c_, bt, n):
+                    return model.prefill_extend(
                         p, {"tokens": t, "block_table": bt}, c_, ccfg,
-                        n_valid=n),
-                    donate_argnums=(2,))
+                        n_valid=n)
             else:
                 def _decode_step(p, t, c_):
                     logits, c2 = model.decode_step(p, {"tokens": t}, c_, ccfg)
                     return logits, pin(c2)
 
-                self._decode_fn = jax.jit(_decode_step, donate_argnums=(2,))
-                self._extend_fn = jax.jit(
-                    lambda p, t, c_, n: model.prefill_extend(p, {"tokens": t}, c_, ccfg,
-                                                             n_valid=n),
-                    donate_argnums=(2,))
-                self._write_fn = jax.jit(
-                    lambda c_, s_, i: pin(model.write_cache(c_, s_, i)),
-                    donate_argnums=(0,))
+                def _extend_step(p, t, c_, n):
+                    return model.prefill_extend(p, {"tokens": t}, c_, ccfg,
+                                                n_valid=n)
+
+                def _write_slot(c_, s_, i):
+                    return pin(model.write_cache(c_, s_, i))
+
+                self._write_fn = jax.jit(_write_slot, donate_argnums=(0,))
+            self._decode_fn = jax.jit(_decode_step, donate_argnums=(2,))
+            self._extend_fn = jax.jit(_extend_step, donate_argnums=(2,))
             if self.spec:
                 if self.paged:
                     def _verify_step(p, t, c_, bt):
@@ -627,9 +634,10 @@ class ServeEngine:
                 # shapes no output can reuse (donating them just warns).
                 # Paged checkpoints carry their block table, so the rewind
                 # signature is mode-independent.
-                self._rewind_fn = jax.jit(
-                    lambda c_, ck, keep: pin(model.spec_rewind(c_, ck, keep)),
-                    donate_argnums=(0,))
+                def _rewind(c_, ck, keep):
+                    return pin(model.spec_rewind(c_, ck, keep))
+
+                self._rewind_fn = jax.jit(_rewind, donate_argnums=(0,))
                 if self._sampled:
                     # speculative SAMPLING: verify + accept/resample fused
                     # in one jitted dispatch — the acceptance uniforms, the
@@ -675,8 +683,10 @@ class ServeEngine:
             self._chunk_cap = 0
             self.cache = None
             self.caches = [None] * scfg.max_batch
-            self._decode_fn = jax.jit(
-                lambda p, t, c_: model.decode_step(p, {"tokens": t}, c_, ccfg))
+            def _decode_step(p, t, c_):
+                return model.decode_step(p, {"tokens": t}, c_, ccfg)
+
+            self._decode_fn = jax.jit(_decode_step)
         if scfg.crest_enabled:
             self._probe_w = self._dense_head_weight()
             self.crest_state = crest.crest_init(self._probe_w.shape[1], scfg.crest_cfg)
@@ -797,8 +807,7 @@ class ServeEngine:
                 req = self._pop_admittable()
                 if req is None:
                     return
-                req.admitted_at = self.clock.now()
-                self._admission_waits.append(req.admitted_at - req.created_at)
+                self._admitted(req)
                 matched = 0
                 assert not self._slot_pages[slot]
                 if self.prefix is not None:
@@ -822,14 +831,17 @@ class ServeEngine:
                 toks[st.slot, :n] = prompt[st.consumed:st.consumed + n]
                 nv = np.zeros((self.scfg.max_batch,), np.int32)
                 nv[st.slot] = n
-                logits, self.cache = self._extend_fn(
-                    self.params, jnp.asarray(toks), self.cache,
-                    self._bt_dev(), jnp.asarray(nv))
+                with self.telemetry.span(telemetry.PREFILL_CHUNK,
+                                         uid=st.req.uid, n=n):
+                    logits, self.cache = self._extend_fn(
+                        self.params, jnp.asarray(toks), self.cache,
+                        self._bt_dev(), jnp.asarray(nv))
+                self.telemetry.count(telemetry.ADMIT, prompt_tokens=n)
                 st.consumed += n
                 spent += n                  # unshared suffix only
             if st.consumed < len(prompt):
                 return                      # budget exhausted mid-prompt
-            nxt = self._pick(logits[st.slot, -1])
+            nxt = self._first_token(st.req, logits[st.slot, -1])
             self._commit_token(st.req, nxt)
             self.slots[st.slot] = st.req
             if self.prefix is not None:
@@ -854,8 +866,7 @@ class ServeEngine:
                 req = self._pop_admittable()
                 if req is None:
                     return
-                req.admitted_at = self.clock.now()
-                self._admission_waits.append(req.admitted_at - req.created_at)
+                self._admitted(req)
                 sub = self.model.init_cache(1, self._cache_len,
                                             dtype=self.ccfg.resolved_kv_dtype)
                 self._staging = _Staging(req, sub, 0, slot)
@@ -869,15 +880,21 @@ class ServeEngine:
                 n = min(chunk, len(prompt) - st.consumed)
                 toks = np.zeros((1, chunk), np.int32)
                 toks[0, :n] = prompt[st.consumed:st.consumed + n]
-                logits, st.cache = self._extend_fn(
-                    self.params, jnp.asarray(toks), st.cache, jnp.int32(n))
+                with self.telemetry.span(telemetry.PREFILL_CHUNK,
+                                         uid=st.req.uid, n=n):
+                    logits, st.cache = self._extend_fn(
+                        self.params, jnp.asarray(toks), st.cache, jnp.int32(n))
+                self.telemetry.count(telemetry.ADMIT, prompt_tokens=n)
                 st.consumed += n
                 spent += n
             if st.consumed < len(prompt):
                 return                      # budget exhausted mid-prompt
-            nxt = self._pick(logits[0, -1])
+            nxt = self._first_token(st.req, logits[0, -1])
             self._commit_token(st.req, nxt)
-            self.cache = self._write_fn(self.cache, st.cache, jnp.int32(st.slot))
+            with self.telemetry.span(telemetry.SLOT_WRITE, uid=st.req.uid,
+                                     slot=st.slot):
+                self.cache = self._write_fn(self.cache, st.cache,
+                                            jnp.int32(st.slot))
             self.slots[st.slot] = st.req
             if self.spec:
                 # seed the slot's draft context with the visible stream
@@ -895,25 +912,42 @@ class ServeEngine:
                 req = self._pop_admittable()
                 if req is None:
                     return
-                req.admitted_at = self.clock.now()
-                self._admission_waits.append(req.admitted_at - req.created_at)
+                self._admitted(req)
                 toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-                logits, cache = self.model.prefill(
-                    self.params, {"tokens": toks}, self.ccfg, max_len=self.scfg.max_len)
-                nxt = self._pick(logits[0, -1])
+                with self.telemetry.span(telemetry.PREFILL_CHUNK, uid=req.uid,
+                                         n=len(req.prompt)):
+                    logits, cache = self.model.prefill(
+                        self.params, {"tokens": toks}, self.ccfg,
+                        max_len=self.scfg.max_len)
+                self.telemetry.count(telemetry.ADMIT,
+                                     prompt_tokens=len(req.prompt))
+                nxt = self._first_token(req, logits[0, -1])
                 self._commit_token(req, nxt)
                 self.slots[i] = req
                 self.caches[i] = cache
                 # the prefill-generated token may already end the stream
                 self._retire_if_done(req, i, nxt)
 
+    def _admitted(self, req: Request):
+        """Stamp the end of ``req``'s admission wait."""
+        req.admitted_at = self.clock.now()
+        wait = req.admitted_at - req.created_at
+        self._admission_waits.append(wait)
+        self.telemetry.count(telemetry.ADMIT, requests=1, wait_s=wait)
+
+    def _first_token(self, req: Request, row) -> int:
+        """The pick after a prefill: the host waits here for the extend."""
+        with self.telemetry.span(telemetry.FIRST_TOKEN, uid=req.uid):
+            return self._pick(row)
+
     def _admit(self):
-        if self.paged:
-            self._admit_paged()
-        elif self.batched:
-            self._admit_batched()
-        else:
-            self._admit_slotwise()
+        with self.telemetry.span(telemetry.ADMIT):
+            if self.paged:
+                self._admit_paged()
+            elif self.batched:
+                self._admit_batched()
+            else:
+                self._admit_slotwise()
 
     # --------------------------------------------------------------- decode
     def _active(self):
@@ -978,22 +1012,24 @@ class ServeEngine:
         if self.scfg.temperature <= 0.0:
             logits, self.cache = self._decode_fn(self.params, jnp.asarray(toks),
                                                  self.cache, *bt)
-            nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+            picks = jnp.argmax(logits[:, -1], axis=-1)
         else:
             # on-device sampling: one fused decode+categorical dispatch; the
             # per-row Gumbel noise is positional (a function of key + slot
             # index), so active rows never depend on garbage-slot contents
-            sampled, self.cache = self._sample_fn(self.params, jnp.asarray(toks),
-                                                  self.cache, *bt,
-                                                  self._next_sample_key())
-            nxt = np.asarray(sampled)
+            picks, self.cache = self._sample_fn(self.params, jnp.asarray(toks),
+                                                self.cache, *bt,
+                                                self._next_sample_key())
+        with self.telemetry.span(telemetry.DECODE_SYNC):
+            nxt = np.asarray(picks)
         produced = 0
-        for i in active:
-            req = self.slots[i]
-            tok = int(nxt[i])
-            self._commit_token(req, tok)
-            produced += 1
-            self._retire_if_done(req, i, tok)
+        with self.telemetry.span(telemetry.COMMIT):
+            for i in active:
+                req = self.slots[i]
+                tok = int(nxt[i])
+                self._commit_token(req, tok)
+                produced += 1
+                self._retire_if_done(req, i, tok)
         return produced
 
     def _decode_spec(self, active: List[int]) -> int:
@@ -1016,23 +1052,25 @@ class ServeEngine:
         k = self._draft_len
         toks = np.zeros((self.scfg.max_batch, k + 1), np.int32)
         keff = np.zeros(self.scfg.max_batch, np.int32)
-        for i in active:
-            # the draft context is the slot's visible stream (prompt — which
-            # already contains failover-carried tokens — plus every token
-            # emitted since), maintained incrementally; the drafter scans at
-            # most the trailing ``ngram_lookback`` tokens of it
-            ctx = self._spec_ctx[i]
-            toks[i, 0] = ctx[-1]               # == tokens_out[-1], pending
-            toks[i, 1:], keff[i] = ngram_propose(
-                np.asarray(ctx[-self.scfg.ngram_lookback:], np.int32),
-                k, self.scfg.ngram_max)
-            if self.paged:
-                # the verify chunk writes rows used-1 .. used-1+K; rows past
-                # the slot's capacity land in the trash page, matching the
-                # dense path's headroom semantics
-                req = self.slots[i]
-                self._ensure_pages(i, len(req.prompt) + len(req.tokens_out)
-                                   - req.prompt_carried + k)
+        with self.telemetry.span(telemetry.DRAFT):
+            for i in active:
+                # the draft context is the slot's visible stream (prompt —
+                # which already contains failover-carried tokens — plus
+                # every token emitted since), maintained incrementally; the
+                # drafter scans at most the trailing ``ngram_lookback``
+                # tokens of it
+                ctx = self._spec_ctx[i]
+                toks[i, 0] = ctx[-1]               # == tokens_out[-1], pending
+                toks[i, 1:], keff[i] = ngram_propose(
+                    np.asarray(ctx[-self.scfg.ngram_lookback:], np.int32),
+                    k, self.scfg.ngram_max)
+                if self.paged:
+                    # the verify chunk writes rows used-1 .. used-1+K; rows
+                    # past the slot's capacity land in the trash page,
+                    # matching the dense path's headroom semantics
+                    req = self.slots[i]
+                    self._ensure_pages(i, len(req.prompt) + len(req.tokens_out)
+                                       - req.prompt_carried + k)
         bt = (self._bt_dev(),) if self.paged else ()
         if self._sampled:
             # ONE counter draw per engine step (the plain sampled step's
@@ -1040,46 +1078,50 @@ class ServeEngine:
             a_dev, fin_dev, self.cache, ckpt = self._spec_sample_fn(
                 self.params, jnp.asarray(toks), self.cache, *bt,
                 jnp.asarray(keff), self._next_sample_key())
-            acc = np.asarray(a_dev)
-            fin = np.asarray(fin_dev)
+            with self.telemetry.span(telemetry.DECODE_SYNC):
+                acc = np.asarray(a_dev)
+                fin = np.asarray(fin_dev)
         else:
             logits, self.cache, ckpt = self._verify_fn(
                 self.params, jnp.asarray(toks), self.cache, *bt)
-            greedy = np.asarray(jnp.argmax(logits, axis=-1))  # (B, K+1)
+            picks = jnp.argmax(logits, axis=-1)
+            with self.telemetry.span(telemetry.DECODE_SYNC):
+                greedy = np.asarray(picks)              # (B, K+1)
         keep = np.zeros(self.scfg.max_batch, np.int32)
         produced = 0
-        for i in active:
-            req = self.slots[i]
-            if self._sampled:
-                a = int(acc[i])
-                # accepted drafts verbatim, then the resampled/bonus token
-                seq = [int(toks[i, j + 1]) for j in range(a)] + [int(fin[i])]
-            else:
-                a = 0
-                while a < keff[i] and greedy[i, a] == toks[i, a + 1]:
-                    a += 1
-                seq = [int(greedy[i, j]) for j in range(a + 1)]
-            keep[i] = a + 1                     # accepted drafts + pending token
-            self._spec_slot_steps += 1
-            # commit one token at a time so eos / max_new / context-limit
-            # retirement fires at EXACTLY the token where plain decode
-            # would have stopped
-            delivered = 0
-            ctx = self._spec_ctx[i]
-            for tok in seq:
-                self._commit_token(req, tok)
-                ctx.append(tok)
-                delivered += 1
-                self._retire_if_done(req, i, tok)
-                if req.done:
-                    break
-            # acceptance counts only DELIVERED drafts (retirement may truncate
-            # mid-acceptance), keeping tokens/step/slot = accepted_per_step + 1
-            self._accepted_drafts += delivered - 1
-            produced += delivered
-            lb = self.scfg.ngram_lookback
-            if len(ctx) > 2 * lb:               # drafter only reads the tail
-                del ctx[:len(ctx) - lb]
+        with self.telemetry.span(telemetry.COMMIT):
+            for i in active:
+                req = self.slots[i]
+                if self._sampled:
+                    a = int(acc[i])
+                    # accepted drafts verbatim, then the resampled/bonus token
+                    seq = [int(toks[i, j + 1]) for j in range(a)] + [int(fin[i])]
+                else:
+                    a = 0
+                    while a < keff[i] and greedy[i, a] == toks[i, a + 1]:
+                        a += 1
+                    seq = [int(greedy[i, j]) for j in range(a + 1)]
+                keep[i] = a + 1                     # accepted drafts + pending token
+                self._spec_slot_steps += 1
+                # commit one token at a time so eos / max_new / context-limit
+                # retirement fires at EXACTLY the token where plain decode
+                # would have stopped
+                delivered = 0
+                ctx = self._spec_ctx[i]
+                for tok in seq:
+                    self._commit_token(req, tok)
+                    ctx.append(tok)
+                    delivered += 1
+                    self._retire_if_done(req, i, tok)
+                    if req.done:
+                        break
+                # acceptance counts only DELIVERED drafts (retirement may truncate
+                # mid-acceptance), keeping tokens/step/slot = accepted_per_step + 1
+                self._accepted_drafts += delivered - 1
+                produced += delivered
+                lb = self.scfg.ngram_lookback
+                if len(ctx) > 2 * lb:               # drafter only reads the tail
+                    del ctx[:len(ctx) - lb]
         # roll every slot back to its accept boundary (inactive slots:
         # keep=0 — a full rewind, restoring the pre-verify cache)
         self.cache = self._rewind_fn(self.cache, ckpt, jnp.asarray(keep))
@@ -1122,19 +1164,24 @@ class ServeEngine:
 
     def step(self) -> int:
         """One engine step; returns number of decode tokens produced."""
-        with self._sharded_scope():
+        tel = self.telemetry
+        with tel.step(active=len(self._active()), queued=len(self.queue)), \
+                self._sharded_scope():
             self._admit()
             active = self._active()
             if not active:
                 return 0
-            t0 = self.clock.now()
-            self._steps += 1
-            if self.scfg.crest_enabled and self._steps % self.scfg.crest_every == 0:
-                self._crest_probe()
-            produced = (self._decode_spec(active) if self.spec
-                        else self._decode_batched(active) if self.batched
-                        else self._decode_slotwise(active))
-            self.step_times.append(self.clock.now() - t0)
+            with tel.span(telemetry.DECODE, active=len(active)):
+                t0 = self.clock.now()
+                self._steps += 1
+                if (self.scfg.crest_enabled
+                        and self._steps % self.scfg.crest_every == 0):
+                    self._crest_probe()
+                produced = (self._decode_spec(active) if self.spec
+                            else self._decode_batched(active) if self.batched
+                            else self._decode_slotwise(active))
+                self.step_times.append(self.clock.now() - t0)
+            tel.count(telemetry.DECODE, tokens=produced, slot_steps=len(active))
             self._decode_tokens += produced
             return produced
 
@@ -1352,7 +1399,7 @@ class ServeEngine:
     def metrics(self) -> dict:
         """Throughput/latency counters for the dashboard & benchmarks."""
         st = np.asarray(self.step_times, np.float64)
-        total = float(st.sum()) if st.size else 0.0
+        phases = self.telemetry.snapshot()
         return {
             **self.latency_percentiles(self._retired),
             "batched": self.batched,
@@ -1381,13 +1428,15 @@ class ServeEngine:
                                   if self._spec_slot_steps else 0.0),
             "steps": int(st.size),
             "decode_tokens": self._decode_tokens,
-            "tokens_per_s": (self._decode_tokens / total) if total > 0 else 0.0,
             "admission_wait_s_mean": (float(np.mean(self._admission_waits))
                                       if self._admission_waits else 0.0),
             "step_time_p50_s": float(np.percentile(st, 50)) if st.size else 0.0,
             "step_time_p99_s": float(np.percentile(st, 99)) if st.size else 0.0,
             "requests_finished": len(self._retired) - self._rejected,
             "requests_rejected": self._rejected,
+            # calls, seconds and work counts of each engine phase
+            "phases": phases,
+            "compiles": int(phases.get(telemetry.COMPILE, {}).get("calls", 0)),
         }
 
     def run_until_drained(self, max_steps: int = 10_000) -> List[Request]:

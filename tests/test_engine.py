@@ -392,7 +392,6 @@ def test_max_new_tokens_retirement_and_queue_drain(tiny_model):
     m = eng.metrics()
     assert m["requests_finished"] == 6
     assert m["decode_tokens"] == 6 * 4          # first token comes from prefill
-    assert m["tokens_per_s"] > 0
 
 
 def test_eos_retirement_frees_slot_early(tiny_model):
