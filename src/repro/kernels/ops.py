@@ -56,26 +56,30 @@ def cascade_matmul(
     scales: jax.Array,
     bias: jax.Array | None = None,
     *,
-    block_m: int = 128,
-    block_n: int = 256,
-    block_k: int = 512,
+    block_m: int = 256,
+    block_n: int = 1024,
+    block_k: int = 2048,
     out_dtype=jnp.float32,
     interpret: bool | None = None,
     exact_dequant: bool | None = None,
 ) -> jax.Array:
     """FP4-packed weight matmul: x (.., K) @ Wq (K, N) -> (.., N).
 
-    Leading dims of x are flattened to M and padded to the M block (at
-    most ``block_m``, at least M rounded up to 8). N and K get their blocks
-    from :func:`fit_block` with caps ``block_n`` / ``block_k`` (K blocks
-    must also divide the scale group); an unaligned N or per-column-scale K
-    is zero-padded, never blocked whole. Odd-K weights (quantize_weight
-    zero-row pad-to-pack) are handled by padding the activations with a
-    matching zero column.
+    Leading dims of x are flattened to M. The blocks follow the call's
+    shapes: the whole M is one block up to ``block_m`` (so each weight tile
+    is decoded once, for the decode grid and the extend chunk alike), else
+    blocks of ``block_m``. N and K get their blocks from :func:`fit_block`
+    with caps ``block_n`` / ``block_k`` (K blocks must also divide the
+    scale group); an unaligned N or per-column-scale K is zero-padded,
+    never blocked whole. The caps keep a grid step's codes at most
+    ``block_n * block_k / 2`` bytes (1 MiB by default): large enough that
+    the per-step overhead is small against the decode, small enough for
+    VMEM at M = 256. Odd-K weights (quantize_weight zero-row pad-to-pack)
+    are handled by padding the activations with a matching zero column.
 
     ``exact_dequant`` defaults to the resolved ``interpret`` value: compiled
-    (TPU) runs the fast bf16-MXU kernel; interpret mode (CPU/CI) runs a
-    single-block grid whose kernel body performs the same dequantize ->
+    (TPU) runs the fast bf16-MXU kernel; interpret mode (CPU/CI) runs one
+    block whose kernel body performs the same dequantize ->
     dot -> bias operations as the jnp serving path on the same shapes, so
     results are bit-identical to ``cascade.linear_apply``'s XLA branch —
     the fused serving path's token-exactness contract.
@@ -97,16 +101,14 @@ def cascade_matmul(
     bias2 = jnp.zeros((1, n), jnp.float32) if bias is None else bias.reshape(1, n).astype(jnp.float32)
     if exact_dequant:
         assert interpret, "exact_dequant is the interpret-mode parity path"
-        # single-block grid on the unpadded operands (interpret mode needs no
-        # block alignment); per-row scales broadcast exactly like the jnp
+        # one block on the unpadded operands (interpret mode needs no block
+        # alignment); per-row scales broadcast exactly like the jnp
         # dequant's group reshape
         group = kdim // scales.shape[0]
         s_full = jnp.repeat(scales, group, axis=0)          # (K, N)
-        out = _cm.cascade_matmul_pallas(
+        out = _cm.cascade_matmul_exact_pallas(
             x2, packed, s_full, bias2,
-            block_m=x2.shape[0], block_n=n, block_k=kdim,
-            out_dtype=out_dtype, compute_dtype=out_dtype,
-            exact_dequant=True, has_bias=bias is not None, interpret=True,
+            out_dtype=out_dtype, has_bias=bias is not None,
         )
         return out.reshape(*lead, n)
     bm = min(block_m, -(-m // 8) * 8)

@@ -51,7 +51,8 @@ def _compile(fn, *args) -> str:
 @pytest.mark.parametrize("k,n", [(D_MODEL, D_MODEL), (D_MODEL, D_FF),
                                  (D_FF, D_MODEL), (D_MODEL, VOCAB)],
                          ids=["qkvo", "gate_up", "down", "lm_head"])
-@pytest.mark.parametrize("m", [8, 32], ids=["decode_b8", "chunk32"])
+@pytest.mark.parametrize("m", [8, 16, 32, 256],
+                         ids=["decode_b8", "decode_b16", "chunk32", "chunk256"])
 def test_cascade_matmul_compiles(one_chip, m, k, n):
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     text = _compile(

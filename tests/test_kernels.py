@@ -15,16 +15,21 @@ jax.config.update("jax_platform_name", "cpu")
     (48, 64, 32, 16, 16, 32),      # m padded to block
     (128, 256, 128, 64, 64, 64),   # multi-block all dims
     (8, 128, 64, 8, 32, 32),       # K-grid accumulation
+    (16, 640, 256, 16, 128, 128),  # K/2 = 320: no multiple of 128 divides it
+    # the blocks the call's shapes choose (None: the default caps): the
+    # decode grid's M and the extend chunk's, each one M block
+    (16, 512, 384, None, None, None),
+    (256, 512, 384, None, None, None),
 ])
 @pytest.mark.parametrize("xdtype", [jnp.float32, jnp.bfloat16])
 def test_cascade_matmul_vs_ref(m, k, n, bm, bn, bk, xdtype):
     key = jax.random.PRNGKey(m * 1000 + k + n)
     w = jax.random.normal(key, (k, n)) * 0.1
-    packed, scales = quant.quantize_weight(w, group_size=bk)
+    packed, scales = quant.quantize_weight(w, group_size=bk or 0)
     x = (jax.random.normal(jax.random.PRNGKey(1), (m, k)) * 0.5).astype(xdtype)
     bias = jax.random.normal(jax.random.PRNGKey(2), (n,))
-    out_k = ops.cascade_matmul(x, packed, scales, bias,
-                               block_m=bm, block_n=bn, block_k=bk,
+    blocks = {} if bm is None else dict(block_m=bm, block_n=bn, block_k=bk)
+    out_k = ops.cascade_matmul(x, packed, scales, bias, **blocks,
                                interpret=True, exact_dequant=False)
     out_r = ops.cascade_matmul_ref(x, packed, scales, bias)
     # the kernel feeds the MXU in bf16 BY DESIGN (TPU path); XLA-CPU's bf16
@@ -71,16 +76,39 @@ def test_cascade_matmul_pads_unaligned_dims(m, k, n):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-2, rtol=3e-2)
 
 
-def test_cascade_matmul_groupwise_scales():
-    k, n = 128, 32
+@pytest.mark.parametrize("k,n,group,blocks", [
+    (128, 32, 32, dict(block_m=16, block_n=32, block_k=32)),
+    # the default caps: the K block is the whole group, one per grid step
+    (512, 256, 128, {}),
+    (4096, 128, 1024, {}),
+])
+def test_cascade_matmul_groupwise_scales(k, n, group, blocks):
     w = jax.random.normal(jax.random.PRNGKey(3), (k, n)) * 0.1
-    packed, scales = quant.quantize_weight(w, group_size=32)
-    assert scales.shape == (4, n)
+    packed, scales = quant.quantize_weight(w, group_size=group)
+    assert scales.shape == (k // group, n)
     x = jax.random.normal(jax.random.PRNGKey(4), (16, k)) * 0.5
-    out = ops.cascade_matmul(x, packed, scales, block_m=16, block_n=32, block_k=32,
+    out = ops.cascade_matmul(x, packed, scales, **blocks,
                              interpret=True, exact_dequant=False)
     ref = ops.cascade_matmul_ref(x, packed, scales)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-2, rtol=3e-2)
+
+
+@pytest.mark.parametrize("row", [6, 7], ids=["even_k_row", "odd_k_row"])
+def test_cascade_matmul_decodes_every_code(row):
+    """Each of the 16 FP4 codes (±0 included) in one column, in an even or
+    an odd K row (the low or the high nibble of its byte): a one-hot
+    activation on that row makes the output the decoded weight times its
+    column's scale, exactly."""
+    k, n = 32, 128
+    codes = np.zeros((k, n), np.uint8)
+    codes[row, :16] = np.arange(16)
+    codes[row, 16:] = np.random.default_rng(row).integers(0, 16, n - 16)
+    packed = quant.pack_fp4(jnp.asarray(codes), axis=0)
+    scales = jnp.asarray(2.0 ** np.arange(-3, n - 3) % 7.0 + 0.25, jnp.float32)[None]
+    x = jnp.zeros((16, k), jnp.float32).at[:, row].set(1.0)
+    out = ops.cascade_matmul(x, packed, scales, interpret=True, exact_dequant=False)
+    want = quant.fp4_decode(jnp.asarray(codes[row])) * scales[0]
+    np.testing.assert_array_equal(np.asarray(out), np.broadcast_to(want, out.shape))
 
 
 @pytest.mark.parametrize("b,hq,hkv,s,d,bq,bk", [
