@@ -1,20 +1,24 @@
 """Finding a cell's files by name.
 
 ``BENCHMARK.json`` (at the checkout root) names the cells and the metrics.
-Everything that belongs to one cell, configuration, traffic mix or metric
-sits in a file of its own, found by the name alone:
+Everything that belongs to one cell, configuration, model type, traffic mix
+or metric sits in a file of its own, found by the name alone:
 
 * ``bench/workloads/<cell>.json``   - configuration, traffic, slot grid, limit
 * ``bench/configs/<config>.json``   - the model as it is run
 * ``bench/traffic/<traffic>.json``  - parameters of the one generator
 * ``bench/metrics/<metric>.py``     - ``read(run) -> float | None``
+* ``bench/families/<model_type>.py`` - the model as the program runs it, its
+  plain reference and its counts, for every configuration whose file names
+  that ``model_type`` (the interface: ``bench/families/__init__.py``)
 
-A later cell, configuration, traffic mix or metric is added with new files
-and entries only: nothing here lists them.
+A later cell, configuration, model type, traffic mix or metric is added
+with new files and entries only: nothing here lists them.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -109,6 +113,20 @@ def metric_reader(name: str, root: Path = ROOT) -> Callable:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+@functools.lru_cache(maxsize=None)
+def family(model_type: str, root: Path = ROOT):
+    """The module ``bench/families/<model_type>.py``, loaded once; a model
+    type with no module is an error that names the file looked for."""
+    path = root / "bench" / "families" / f"{model_type}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no family module for model_type {model_type!r}: "
+                                f"looked for {path}")
+    spec = importlib.util.spec_from_file_location(f"bench_family_{model_type}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_peaks(device_kind: str, root: Path = ROOT) -> dict:

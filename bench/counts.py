@@ -1,20 +1,25 @@
 """Operations and bytes the algorithm needs, from the model's shapes.
 
-Derived from ``repro.core.flops`` (dense-transformer parameter counts and
-the 2 * N * tokens + attention FLOP rule), kept here so that no program
-change can move a roofline. They count the work the model needs, never what
-a kernel happens to move: padding, inactive slots and replicated copies are
+Derived from ``repro.core.flops`` (parameter counts and the 2 * N * tokens
++ attention FLOP rule), kept with the benchmark so that no program change
+can move a roofline. They count the work the model needs, never what a
+kernel happens to move: padding, inactive slots and replicated copies are
 not counted, so a share of a roofline cannot pass 100% because a later
 kernel skips waste.
 
-Shapes are read from a configuration file's keys (``hidden_size``,
-``intermediate_size``, ``num_attention_heads``, ``num_key_value_heads``,
-``head_dim``, ``vocab_size``, ``num_hidden_layers``).
+What a model's work is depends on its kind: each count below dispatches to
+the family that the configuration's ``model_type`` names
+(``bench/families/<model_type>.py``), which reads its shapes from the
+configuration file's keys and, for work that depends on what the program
+did (routing), from the run it is given. This module keeps what every
+family shares: ``Work``, one FP4 linear's work and the byte sizes.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import List, Tuple
+
+from bench import cells
 
 #: bytes of one FP4 weight (two codes a byte) and of one per-column f32 scale
 FP4_BYTES = 0.5
@@ -36,31 +41,6 @@ class Work:
         return max(self.flops / peak_flops, self.bytes / peak_bw)
 
 
-def layer_linears(cfg: dict) -> List[Tuple[str, int, int, bool]]:
-    """(name, K, N, has_bias) of every linear of one layer."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, hk, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    bias = bool(cfg.get("attention_bias", False))
-    return [("wq", d, h * hd, bias), ("wk", d, hk * hd, bias),
-            ("wv", d, hk * hd, bias), ("wo", h * hd, d, False),
-            ("w_gate", d, f, False), ("w_up", d, f, False),
-            ("w_down", f, d, False)]
-
-
-def linears(cfg: dict) -> List[Tuple[int, int, bool]]:
-    """(K, N, has_bias) of every linear the model runs per token: all
-    layers' and the output head."""
-    per = [(k, n, b) for _, k, n, b in layer_linears(cfg)]
-    return per * cfg["num_hidden_layers"] + [
-        (cfg["hidden_size"], cfg["vocab_size"], False)]
-
-
-def matmul_params(cfg: dict) -> int:
-    """Weights every token multiplies by (the embedding is a lookup)."""
-    return sum(k * n for k, n, _ in linears(cfg))
-
-
 def linear_work(m: int, k: int, n: int, bias: bool = False) -> Work:
     """One FP4 linear over ``m`` rows: 2*m*k*n FLOPs; its codes and scales
     (and bias) once, the bf16 rows in and out."""
@@ -68,49 +48,49 @@ def linear_work(m: int, k: int, n: int, bias: bool = False) -> Work:
     return Work(2.0 * m * k * n, w + m * (k + n) * BF16)
 
 
+def _family(cfg: dict):
+    return cells.family(cfg["model_type"])
+
+
+def layer_linears(cfg: dict) -> List[Tuple[str, int, int, bool]]:
+    """(name, K, N, has_bias) of every linear of one layer."""
+    return _family(cfg).layer_linears(cfg)
+
+
+def linears(cfg: dict) -> List[Tuple[int, int, bool]]:
+    """(K, N, has_bias) of every linear the model runs per token: all
+    layers' and the output head."""
+    return _family(cfg).linears(cfg)
+
+
+def matmul_params(cfg: dict) -> int:
+    """Weights every token multiplies by (the embedding is a lookup)."""
+    return sum(k * n for k, n, _ in linears(cfg))
+
+
 def kv_bytes_per_token(cfg: dict) -> int:
-    """K and V of one position in every layer, bf16."""
-    return (2 * cfg["num_hidden_layers"] * cfg["num_key_value_heads"]
-            * cfg["head_dim"] * BF16)
+    """The cache bytes of one position in every layer."""
+    return _family(cfg).kv_bytes_per_token(cfg)
 
 
 def attention_decode_work(cfg: dict, positions: float) -> Work:
     """One decode step's attention in ONE layer, summed over its active
-    slots, which attend over ``positions`` valid cache rows in all: q.k and
-    p.v (2 * 2 * H * D FLOPs a row) and each valid K/V row read once."""
-    h, hk, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    return Work(4.0 * positions * h * hd, positions * 2 * hk * hd * BF16)
+    slots, which attend over ``positions`` valid cache rows in all."""
+    return _family(cfg).attention_decode_work(cfg, positions)
 
 
-def decode_step_work(cfg: dict, active: int, positions: float) -> Work:
+def decode_step_work(cfg: dict, active: int, positions: float, run=None) -> Work:
     """One decode step of the whole model for ``active`` slots attending over
-    ``positions`` valid cache rows in all (per layer): every linear's FP4
-    codes and scales, the head included, and the valid K/V rows of every
-    layer."""
-    att = attention_decode_work(cfg, positions)
-    layers = cfg["num_hidden_layers"]
-    return cascade_matmul_work(cfg, active) + Work(att.flops * layers,
-                                                   att.bytes * layers)
+    ``positions`` valid cache rows in all (per layer)."""
+    return _family(cfg).decode_step_work(cfg, active, positions, run=run)
 
 
-def prefill_work(cfg: dict, prompt: int, chunk: int) -> Work:
-    """Prefill of one prompt in chunks of ``chunk`` tokens: 2 * N * tokens
-    plus causal attention (each token attends to itself and every earlier
-    one), weights streamed once per chunk, K/V of every token written."""
-    h, hd = cfg["num_attention_heads"], cfg["head_dim"]
-    layers = cfg["num_hidden_layers"]
-    chunks = -(-prompt // chunk)
-    flops = (2.0 * matmul_params(cfg) * prompt
-             + layers * 4.0 * h * hd * prompt * (prompt + 1) / 2)
-    weights = sum(k * n * FP4_BYTES + n * SCALE_BYTES for k, n, _ in linears(cfg))
-    return Work(flops, chunks * weights + prompt * kv_bytes_per_token(cfg))
+def prefill_work(cfg: dict, prompt: int, chunk: int, run=None) -> Work:
+    """Prefill of one prompt in chunks of ``chunk`` tokens."""
+    return _family(cfg).prefill_work(cfg, prompt, chunk, run=run)
 
 
-def cascade_matmul_work(cfg: dict, active: int) -> Work:
-    """The FP4 matmul kernel's work in one decode step: every linear over
-    the active rows (logical shapes, M x K x N)."""
-    w = Work()
-    for k, n, b in linears(cfg):
-        w = w + linear_work(active, k, n, b)
-    return w
+def cascade_matmul_work(cfg: dict, active: int, run=None) -> Work:
+    """The FP4 matmul kernel's work in one decode step over the active rows
+    (logical shapes, M x K x N)."""
+    return _family(cfg).cascade_matmul_work(cfg, active, run=run)

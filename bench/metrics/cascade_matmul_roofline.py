@@ -12,7 +12,7 @@ def read(run):
     runs = run.traced_decode_runs()
     secs = op_seconds(run.trace, 0, names, within=runs)
     per_step = run.traced_step_mean(
-        lambda s, pos: run.roofline_s(counts.cascade_matmul_work(run.cfg, s.produced)))
+        lambda s, pos: run.roofline_s(counts.cascade_matmul_work(run.cfg, s.produced, run=run)))
     if not secs or per_step is None:
         return None
     return 100.0 * len(runs) * per_step / secs

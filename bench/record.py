@@ -79,7 +79,7 @@ class Record:
     def decode_work(self, steps: list) -> counts.Work:
         w = counts.Work()
         for s, pos in zip(steps, self.step_positions(steps)):
-            w = w + counts.decode_step_work(self.cfg, s.produced, pos)
+            w = w + counts.decode_step_work(self.cfg, s.produced, pos, run=self)
         return w
 
     def prefilled(self) -> list:
@@ -92,7 +92,7 @@ class Record:
         chunk = self.cell.config["serve"]["prefill_chunk"]
         w = counts.Work()
         for r in reqs:
-            w = w + counts.prefill_work(self.cfg, len(r.prompt), chunk)
+            w = w + counts.prefill_work(self.cfg, len(r.prompt), chunk, run=self)
         return w
 
     @property

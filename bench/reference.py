@@ -1,24 +1,22 @@
-"""The plain reference: a float32 forward pass of the Qwen1.5 / Qwen2 block,
-written from the published description and imports nothing of the program.
+"""The plain reference that decides ``correct``: what every model family
+shares. It imports nothing of the program.
 
-    x = embed[tokens]
-    per layer:  h = rmsnorm(x) ; q, k, v = h Wq + bq, h Wk + bk, h Wv + bv
-                q, k = rope(q), rope(k)            (rotate-half, theta)
-                x = x + softmax(q k^T / sqrt(D), causal) v Wo   (GQA: each
-                    group of H / Hkv query heads shares one K/V head)
-                h = rmsnorm(x) ; x = x + (silu(h Wg) * (h Wu)) Wd
-    logits = rmsnorm(x) Wlm
+The model's own float32 pass is its family's ``forward(params, tokens, cfg,
+lowp=False)`` (``bench/families/<model_type>.py``), written from the
+published description; ``forward`` here dispatches to it by the
+configuration's ``model_type``. This module holds the rest:
 
-Weights are the benchmark's own packed FP4 arrays (``bench.weights``),
-decoded here from the E2M1 bit fields times the per-column scale.
-Every matmul runs at ``precision="highest"``, one sequence at a time, with
-the layers under ``lax.scan`` so that one layer's decoded weights are held
-at a time.
-
-``lowp=True`` is the control: the same pass with every matmul input and the
-attention probabilities rounded to float8 (E4M3), the nearest precision
-below the bfloat16 the configuration serves in. ``lowp_picks`` puts its
-choices in the program's place for the check (``bench/control.py``).
+* the served format: the benchmark's own packed FP4 arrays
+  (``bench.weights``), decoded from the E2M1 bit fields times the
+  per-column scale, and the FP4 ``linear`` and ``rmsnorm`` a family's pass
+  is built from, every matmul at ``precision="highest"``;
+* the control: ``lowp=True`` rounds every matmul input and the attention
+  probabilities to float8 (E4M3, ``round_lowp``), the nearest precision
+  below the bfloat16 the configuration serves in; ``lowp_picks`` puts its
+  choices in the program's place for the check (``bench/control.py``);
+* the jitted passes that judge served tokens, one sequence at a time, with
+  the configuration's ``model_type`` and its family's ``REFERENCE_KEYS``
+  as their static configuration.
 """
 from __future__ import annotations
 
@@ -28,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from bench import cells
 
 
 def e2m1(code: jax.Array) -> jax.Array:
@@ -49,62 +49,24 @@ def decode_fp4(codes: jax.Array, scale: jax.Array) -> jax.Array:
     return (w.reshape(g, -1, w.shape[1]) * scale[:, None, :]).reshape(w.shape)
 
 
-def _round(x, lowp: bool):
+def round_lowp(x, lowp: bool):
     return x.astype(jnp.float8_e4m3fn).astype(jnp.float32) if lowp else x
 
 
-def _linear(p: dict, x, lowp: bool):
-    y = jnp.dot(_round(x, lowp), decode_fp4(p["codes"], p["scale"]),
+def linear(p: dict, x, lowp: bool):
+    y = jnp.dot(round_lowp(x, lowp), decode_fp4(p["codes"], p["scale"]),
                 precision="highest")
     return y + p["b"] if "b" in p else y
 
 
-def _rmsnorm(x, scale, eps):
+def rmsnorm(x, scale, eps):
     return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, theta):
-    """x: (T, H, D); rotate-half over the whole head."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = pos[:, None].astype(jnp.float32) * inv[None, :]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-
-
 def forward(params: dict, tokens: jax.Array, cfg: dict, lowp: bool = False):
-    """(T,) token ids -> (T, vocab) float32 logits."""
-    h, hk, d = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                cfg["head_dim"])
-    eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
-    t = tokens.shape[0]
-    pos = jnp.arange(t)
-    causal = pos[:, None] >= pos[None, :]
-    x = params["embed"]["table"][tokens].astype(jnp.float32)
-
-    def layer(x, lp):
-        a = lp["attn"]
-        y = _rmsnorm(x, lp["ln1"]["scale"], eps)
-        q = _rope(_linear(a["wq"], y, lowp).reshape(t, h, d), pos, theta)
-        k = _rope(_linear(a["wk"], y, lowp).reshape(t, hk, d), pos, theta)
-        v = _linear(a["wv"], y, lowp).reshape(t, hk, d)
-        k, v = jnp.repeat(k, h // hk, axis=1), jnp.repeat(v, h // hk, axis=1)
-        s = jnp.einsum("qhd,khd->hqk", _round(q, lowp), _round(k, lowp),
-                       precision="highest") / np.sqrt(d)
-        p = jax.nn.softmax(jnp.where(causal[None], s, -jnp.inf), axis=-1)
-        o = jnp.einsum("hqk,khd->qhd", _round(p, lowp), _round(v, lowp),
-                       precision="highest").reshape(t, h * d)
-        x = x + _linear(a["wo"], o, lowp)
-        m = lp["mlp"]
-        y = _rmsnorm(x, lp["ln2"]["scale"], eps)
-        g = _linear(m["w_gate"], y, lowp)
-        u = _linear(m["w_up"], y, lowp)
-        return x + _linear(m["w_down"], jax.nn.silu(g) * u, lowp), None
-
-    x, _ = lax.scan(layer, x, params["layers"])
-    return _linear(params["lm_head"], _rmsnorm(x, params["final_norm"]["scale"], eps),
-                   lowp)
+    """(T,) token ids -> (T, vocab) float32 logits: the pass of the
+    family that ``cfg["model_type"]`` names."""
+    return cells.family(cfg["model_type"]).forward(params, tokens, cfg, lowp)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg_items",))
@@ -123,9 +85,8 @@ def _lowp_picks(params, tokens, pos, cfg_items):
 
 
 def _cfg_items(cfg: dict) -> tuple:
-    keys = ("num_attention_heads", "num_key_value_heads", "head_dim",
-            "rms_norm_eps", "rope_theta")
-    return tuple((k, cfg[k]) for k in keys)
+    keys = cells.family(cfg["model_type"]).REFERENCE_KEYS
+    return (("model_type", cfg["model_type"]),) + tuple((k, cfg[k]) for k in keys)
 
 
 def _sequence(prompt, served, length: int):

@@ -1,8 +1,9 @@
 """The system under test, built from a cell's files, and the benchmark's
 own driver of it.
 
-The served path is the one ``repro.launch.serve`` serves: a
-``TransformerLM`` in ``serve_fp4`` under ``ServeEngine``, driven through
+The served path is the one ``repro.launch.serve`` serves: the program's
+model that the configuration's family builds (``bench/families/
+<model_type>.py``), in ``serve_fp4`` under ``ServeEngine``, driven through
 ``submit()`` and ``step()``. The driver is derived from
 ``SLORouter.run_trace`` (one replica, no kills): arrivals are submitted
 when due, stamped with the time they were due, and the engine steps while
@@ -16,15 +17,9 @@ from typing import List, Optional
 
 import numpy as np
 
+from bench import cells
 from bench import traffic as T
 from bench.weights import make_params
-
-#: configuration-file keys -> the program's ``ArchConfig`` fields
-ARCH_KEYS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-             "num_attention_heads": "n_heads",
-             "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
-             "intermediate_size": "d_ff", "vocab_size": "vocab",
-             "rope_theta": "rope_theta", "attention_bias": "qkv_bias"}
 
 
 def model_config(cell, size: Optional[dict] = None) -> dict:
@@ -37,18 +32,14 @@ def model_config(cell, size: Optional[dict] = None) -> dict:
 
 def build_engine(cell, cfg: dict, seed: int, devices):
     """(engine, params): the cell's model with weights drawn from ``seed``,
-    under ``ServeEngine`` with the cell's slot grid, on ``devices``."""
+    under ``ServeEngine`` with the cell's slot grid, on ``devices``. The
+    model comes from the family that ``cfg["model_type"]`` names."""
     import jax.numpy as jnp
-    from repro.configs.base import ArchConfig
     from repro.core.cascade import CascadeConfig
-    from repro.models.registry import build_model
     from repro.serve.engine import ServeConfig, ServeEngine
 
-    if cfg.get("hidden_act") != "silu" or cfg.get("tie_word_embeddings"):
-        raise ValueError("only SwiGLU blocks with an untied head are described")
-    arch = ArchConfig(name=cell.config_name, family="dense",
-                      **{f: cfg[k] for k, f in ARCH_KEYS.items()})
-    model = build_model(arch)
+    family = cells.family(cfg["model_type"])
+    model = family.model(cell.config_name, cfg)
     serve = cell.config["serve"]
     dtype = getattr(jnp, serve["compute_dtype"])
     ccfg = CascadeConfig(mode=serve["mode"], compute_dtype=dtype,
@@ -61,7 +52,8 @@ def build_engine(cell, cfg: dict, seed: int, devices):
         mesh = make_mesh((m["data"], m["model"]), ("data", "model"),
                          devices=devices[: m["data"] * m["model"]])
         shardings_fn = lambda tree: serving_param_shardings(tree, mesh, m["tp_policy"])
-    params = make_params(model, ccfg, seed, shardings_fn)
+    params = make_params(model, ccfg, seed, shardings_fn,
+                         getattr(family, "fill", None))
     w = cell.workload
     scfg = ServeConfig(max_batch=w["max_batch"], max_len=w["max_len"],
                        prefill_chunk=serve["prefill_chunk"],

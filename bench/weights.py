@@ -14,6 +14,9 @@ serves it from:
 * ``b`` (bias, f32): normal, standard deviation 0.1;
 * a norm's ``scale`` (f32): ``1 + 0.1 * normal``;
 * ``table`` (the embedding, bf16): standard normal.
+
+A leaf none of these covers is drawn by the model family's own ``fill``
+(``bench/families/<model_type>.py``), where it has one, or raises.
 """
 from __future__ import annotations
 
@@ -39,9 +42,10 @@ def _keys(path) -> list:
     return [getattr(p, "key", getattr(p, "idx", None)) for p in path]
 
 
-def _fill(names: list, sds, key, k_in: int | None):
+def _fill(names: list, sds, key, k_in: int | None, rule=None):
     """One leaf; ``k_in`` is the input width when the leaf is a linear's
-    ``scale`` (it sits beside ``codes``)."""
+    ``scale`` (it sits beside ``codes``). ``rule`` is the family's ``fill``
+    for leaves the generic rules do not cover."""
     leaf = names[-1]
     shape, dtype = sds.shape, sds.dtype
     if leaf == "codes":
@@ -55,14 +59,18 @@ def _fill(names: list, sds, key, k_in: int | None):
         return (1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
     if leaf == "table":
         return jax.random.normal(key, shape, jnp.float32).astype(dtype)
+    drawn = rule(names, sds, key, k_in) if rule is not None else None
+    if drawn is not None:
+        return drawn
     raise ValueError(f"no rule to draw the weight leaf {'/'.join(map(str, names))}")
 
 
-def make_params(model, ccfg, seed: int, shardings_fn=None):
+def make_params(model, ccfg, seed: int, shardings_fn=None, fill=None):
     """The model's serving params, drawn from ``seed`` in one jitted call.
 
     ``shardings_fn(abstract_tree)`` gives the ``out_shardings`` (a mesh
-    engine's placement), or ``None`` for one device."""
+    engine's placement), or ``None`` for one device. ``fill`` is the model
+    family's rule for leaves the generic rules do not cover."""
     abstract = jax.eval_shape(lambda k: model.init_params(k, ccfg),
                               jax.random.PRNGKey(0))
     flat, treedef = jax.tree_util.tree_flatten_with_path(abstract)
@@ -71,7 +79,8 @@ def make_params(model, ccfg, seed: int, shardings_fn=None):
               if n[-1] == "codes"}
 
     def build(key):
-        out = [_fill(n, sds, jax.random.fold_in(key, i), code_k.get(tuple(n[:-1])))
+        out = [_fill(n, sds, jax.random.fold_in(key, i), code_k.get(tuple(n[:-1])),
+                     fill)
                for i, (n, (_, sds)) in enumerate(zip(names, flat))]
         return jax.tree_util.tree_unflatten(treedef, out)
 
