@@ -50,7 +50,19 @@ class ArchConfig:
     first_dense_layers: int = 0  # deepseek-v2: layer 0 is a dense MLP
     dense_d_ff: int = 0          # ffn dim of those dense layers
     moe_capacity_factor: float = 1.25
-    moe_renorm: bool = True
+    moe_renorm: bool = True      # norm_topk_prob: gates renormalised to sum 1
+    moe_scale: float = 1.0       # routed_scaling_factor (only without renorm)
+    moe_groups: int = 1          # n_group: group-limited greedy when > 1
+    moe_topk_groups: int = 1     # topk_group: groups a token may route into
+    n_routed_experts: int = 0    # router width; 0 -> n_experts (all held)
+    first_held_expert: int = 0   # this chip holds experts [first, first + n_experts)
+    # --- yarn rope scaling (deepseek-v2); yarn_factor 0 = plain rope ---
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     # --- mla (deepseek-v2) ---
     kv_lora: int = 0
     q_lora: int = 0
@@ -69,6 +81,12 @@ class ArchConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def router_width(self) -> int:
+        """Experts the router scores: the published count, of which this
+        chip holds ``n_experts``."""
+        return self.n_routed_experts or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)

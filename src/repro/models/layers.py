@@ -7,6 +7,7 @@ FP4 serving format / QAT / column-parallel distribution apply uniformly.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional
 
 import jax
@@ -58,13 +59,49 @@ def rope_freqs(head_dim: int, theta: float, fraction: float = 1.0) -> jax.Array:
     return inv  # (rot/2,)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array) -> jax.Array:
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term ``0.1 * mscale * ln(factor) + 1``
+    (1 for a factor of at most 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_range(dim: int, base: float, original_max: int, beta_fast: float,
+               beta_slow: float) -> tuple[int, int]:
+    """The rotary pairs ``[low, high]`` over which YaRN ramps from the
+    original frequencies to the interpolated ones: pair ``i`` rotates
+    ``original_max / (2 pi base^(2i/dim))`` times over the original context,
+    and ``low`` / ``high`` are where that count is ``beta_fast`` /
+    ``beta_slow`` (floor / ceil, clamped to ``[0, dim - 1]``)."""
+    def corr(rot):
+        return dim * math.log(original_max / (rot * 2 * math.pi)) / (2 * math.log(base))
+
+    return max(math.floor(corr(beta_fast)), 0), min(math.ceil(corr(beta_slow)), dim - 1)
+
+
+def yarn_freqs(dim: int, base: float, factor: float, original_max: int,
+               beta_fast: float, beta_slow: float) -> jax.Array:
+    """YaRN ``inv_freq`` (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``):
+    ``base^(-2i/dim)`` below ``low``, the same over ``factor`` above
+    ``high``, a linear ramp between."""
+    extra = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    low, high = yarn_range(dim, base, original_max, beta_fast, beta_slow)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return extra / factor * ramp + extra * (1 - ramp)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array,
+               mscale: float = 1.0) -> jax.Array:
     """x: (B, S, H, D); positions: (B, S) int32. Rotates the first
-    2*len(inv_freq) channels, passes the rest through (partial rotary)."""
+    2*len(inv_freq) channels, passes the rest through (partial rotary).
+    ``mscale`` multiplies cos and sin (YaRN)."""
     rot2 = inv_freq.shape[0]
     ang = positions[..., None].astype(jnp.float32) * inv_freq  # (B, S, r/2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x_rot, x_pass = x[..., : 2 * rot2], x[..., 2 * rot2:]
     x1, x2 = x_rot[..., :rot2], x_rot[..., rot2:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

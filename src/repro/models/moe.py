@@ -50,7 +50,7 @@ def moe_ffn_init(key: jax.Array, cfg: ArchConfig, ccfg: CascadeConfig) -> dict:
     ks = jax.random.split(key, 5)
     d, e, dff = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
     p = {
-        "router": jax.random.normal(ks[0], (d, e), jnp.float32) * 0.02,
+        "router": jax.random.normal(ks[0], (d, cfg.router_width), jnp.float32) * 0.02,
         "wg": cascade.expert_linear_init(ks[1], e, d, dff, ccfg),
         "wu": cascade.expert_linear_init(ks[2], e, d, dff, ccfg),
         "wd": cascade.expert_linear_init(ks[3], e, dff, d, ccfg),
@@ -60,14 +60,49 @@ def moe_ffn_init(key: jax.Array, cfg: ArchConfig, ccfg: CascadeConfig) -> dict:
     return p
 
 
+def route(xf: jax.Array, router: jax.Array, cfg: ArchConfig):
+    """Routing of (T, d) tokens over every expert the router scores, held
+    here or not: ``(gates, idx)``, each (T, top_k), with global expert ids.
+
+    Softmax scores. With ``moe_groups > 1``, group-limited greedy: a group's
+    score is its best expert's, the ``moe_topk_groups`` best groups stay and
+    the rest are zeroed, and the top-k are taken from what is left. Gates
+    are renormalised to sum 1 (``moe_renorm``), else multiplied by
+    ``moe_scale``. The router runs in float32 (``HIGHEST``: a TPU would
+    otherwise round its operands to bfloat16 and flip near-tied picks)."""
+    probs = jax.nn.softmax(jnp.dot(xf.astype(jnp.float32), router,
+                                   precision=lax.Precision.HIGHEST), axis=-1)
+    if cfg.moe_groups > 1:
+        t, e = probs.shape
+        g = cfg.moe_groups
+        best = jnp.max(probs.reshape(t, g, e // g), axis=-1)             # (T, G)
+        _, top = lax.top_k(best, cfg.moe_topk_groups)
+        kept = jnp.any(top[:, :, None] == jnp.arange(g), axis=1)          # (T, G)
+        probs = jnp.where(jnp.repeat(kept, e // g, axis=1), probs, 0.0)
+    gates, idx = lax.top_k(probs, cfg.moe_top_k)
+    if cfg.moe_renorm:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    elif cfg.moe_scale != 1.0:
+        gates = gates * cfg.moe_scale
+    return gates, idx
+
+
 def _capacity(n_tokens: int, cfg: ArchConfig) -> int:
     c = math.ceil(cfg.moe_capacity_factor * n_tokens * cfg.moe_top_k / cfg.n_experts)
     return max(8, -(-c // 8) * 8)  # round up to a multiple of 8
 
 
 def moe_ffn_apply(params: dict, x: jax.Array, cfg: ArchConfig, ccfg: CascadeConfig,
-                  no_drop: bool = False) -> jax.Array:
-    """Capacity-dispatched routed experts.
+                  no_drop: bool = False, routes: bool = False):
+    """Capacity-dispatched routed experts, this chip's share of them.
+
+    The router scores all ``cfg.router_width`` experts; this chip holds
+    ``n_experts`` of them, from ``first_held_expert``. Assignments to the
+    experts it does not hold are dropped before dispatch, so the buffer,
+    its capacity and the expert matmuls cover the held experts only, and
+    the result is the held experts' part plus the shared experts. With
+    ``routes``, returns ``(y, routed)``: routed (T, n_experts) bool, which
+    held experts each token went to.
 
     ``no_drop=True`` (serving paths) sizes the buffer for worst-case
     routing skew so NO token is ever capacity-dropped: per-token outputs
@@ -90,17 +125,21 @@ def moe_ffn_apply(params: dict, x: jax.Array, cfg: ArchConfig, ccfg: CascadeConf
         # train keeps data-sharded tokens and the documented dispatch reduce)
         xf = constrain_replicated(xf)
 
-    logits = jnp.dot(xf.astype(jnp.float32), params["router"])       # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = lax.top_k(probs, k)                                  # (T, k)
-    if cfg.moe_renorm:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    gates, idx = route(xf, params["router"], cfg)                    # (T, k)
+    share = cfg.router_width != e
+    if share:
+        # held experts by their index here; e (no buffer row) for the rest
+        idx = idx - cfg.first_held_expert
+        idx = jnp.where((idx >= 0) & (idx < e), idx, e)
 
     flat_e = idx.reshape(-1)                                          # (T*k,)
     onehot = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)
+    at = jnp.minimum(flat_e, e - 1) if share else flat_e
     pos_in_e = jnp.take_along_axis(jnp.cumsum(onehot, axis=0) - 1,
-                                   flat_e[:, None], axis=1)[:, 0]     # (T*k,)
+                                   at[:, None], axis=1)[:, 0]         # (T*k,)
     keep = pos_in_e < cap
+    if share:
+        keep = keep & (flat_e < e)
     dst = jnp.where(keep, flat_e * cap + pos_in_e, e * cap)           # OOB = dropped
 
     xk = jnp.repeat(xf, k, axis=0)                                    # (T*k, d) token-major
@@ -134,7 +173,10 @@ def moe_ffn_apply(params: dict, x: jax.Array, cfg: ArchConfig, ccfg: CascadeConf
 
     if "shared" in params:
         y = y + L.mlp_apply(params["shared"], xf, "swiglu", ccfg).astype(jnp.float32)
-    return y.reshape(b, s, d).astype(x.dtype)
+    y = y.reshape(b, s, d).astype(x.dtype)
+    if routes:
+        return y, jnp.any(idx[:, :, None] == jnp.arange(e), axis=1)
+    return y
 
 
 # ---------------------------------------------------------------------------
@@ -178,17 +220,37 @@ def _mla_qkr(params, x, cfg, ccfg, positions):
     q_rope = constrain_matmul_input(q_rope)
     c_kv = constrain_matmul_input(c_kv)
     k_rope = constrain_matmul_input(k_rope)
-    inv = L.rope_freqs(cfg.qk_rope_dim, cfg.rope_theta, 1.0)
-    q_rope = L.apply_rope(q_rope, positions, inv)
-    k_rope = L.apply_rope(k_rope, positions, inv)[:, :, 0, :]
+    inv, mscale = mla_rope(cfg)
+    q_rope = L.apply_rope(q_rope, positions, inv, mscale)
+    k_rope = L.apply_rope(k_rope, positions, inv, mscale)[:, :, 0, :]
     return q_nope, q_rope, c_kv, k_rope
+
+
+def mla_rope(cfg: ArchConfig):
+    """``(inv_freq, mscale)`` of the decoupled rope head: YaRN where
+    ``yarn_factor`` is set (cos and sin times ``m(mscale) / m(mscale_all_dim)``),
+    else plain rope."""
+    if not cfg.yarn_factor:
+        return L.rope_freqs(cfg.qk_rope_dim, cfg.rope_theta, 1.0), 1.0
+    f = cfg.yarn_factor
+    inv = L.yarn_freqs(cfg.qk_rope_dim, cfg.rope_theta, f, cfg.yarn_original_max,
+                       cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    return inv, L.yarn_mscale(f, cfg.yarn_mscale) / L.yarn_mscale(f, cfg.yarn_mscale_all_dim)
+
+
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """``(nope + rope)^-0.5``, times YaRN's ``m(mscale_all_dim)^2`` where set."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= L.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
 
 
 def mla_apply(params, x, cfg: ArchConfig, ccfg, cache=None, mode="full", max_len=None,
               n_valid=None):
     b, s, _ = x.shape
     h = cfg.n_heads
-    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    scale = mla_softmax_scale(cfg)
     if cache is not None:
         positions = (L.pos_rows(cache["pos"], b)[:, None]
                      + jnp.arange(s, dtype=jnp.int32)[None, :])
@@ -287,6 +349,9 @@ def mla_cache_init_paged(batch: int, num_pages: int, page_size: int,
 # ---------------------------------------------------------------------------
 
 class MoELM(StackedCacheMixin):
+    #: ``decode_step(..., routes=True)`` also returns the held experts' routes
+    routes_experts = True
+
     def __init__(self, cfg: ArchConfig):
         self.cfg = cfg
         self.use_mla = cfg.kv_lora > 0
@@ -343,7 +408,10 @@ class MoELM(StackedCacheMixin):
         return L.attn_apply(lp, x, self.attn_cfg, ccfg, cache=cache, mode=mode,
                             max_len=max_len, n_valid=n_valid)
 
-    def _block(self, lp, x, ccfg, cache, mode, moe: bool, max_len=None, n_valid=None):
+    def _block(self, lp, x, ccfg, cache, mode, moe: bool, max_len=None, n_valid=None,
+               routes=False):
+        """``(x, cache)``; with ``routes`` (an MoE block), ``(x, cache,
+        routed)``: which held experts each token went to."""
         cfg = self.cfg
         h, nc = self._attn_apply(lp["attn"], L.norm_apply(lp["ln1"], x, cfg.norm_type),
                                  ccfg, cache, mode, max_len, n_valid)
@@ -353,24 +421,35 @@ class MoELM(StackedCacheMixin):
             # serving modes dispatch drop-free: capacity drops would make a
             # token's output depend on unrelated slots / chunk boundaries,
             # breaking batched-vs-slotwise parity (train keeps drops)
-            x = x + self._moe_ffn(lp["moe"], u, ccfg, no_drop=(mode != "full"))
+            y = self._moe_ffn(lp["moe"], u, ccfg, no_drop=(mode != "full"),
+                              routes=routes)
+            if routes:
+                y, routed = y
+                return constrain_residual(x + y), nc, routed
+            x = x + y
         else:
             x = x + L.mlp_apply(lp["mlp"], u, "swiglu", ccfg)
         return constrain_residual(x), nc
 
-    def _moe_ffn(self, lp_moe, u, ccfg, no_drop=False):
+    def _moe_ffn(self, lp_moe, u, ccfg, no_drop=False, routes=False):
         """Dispatch strategy: shard_map expert parallelism when the launcher
         installed a policy with moe_ep=True (kills the GSPMD scatter
         all-reduce, see models/moe_shardmap.py); jit capacity-dispatch
         otherwise (CPU tests / no mesh). The EP path keeps capacity
-        semantics (it is a training/lowering surface, not the engine's)."""
+        semantics (it is a training/lowering surface, not the engine's); it
+        holds every expert the router scores and returns no routes, so a
+        held share or ``routes`` is refused there."""
         from repro.distributed.sharding import get_activation_policy
         pol = get_activation_policy()
         if pol and pol.get("moe_ep") and pol.get("mesh") is not None:
+            if routes or self.cfg.router_width != self.cfg.n_experts:
+                raise NotImplementedError(
+                    "the expert-parallel path holds every expert and returns "
+                    "no routes: serve a held share or count routes without moe_ep")
             from repro.models.moe_shardmap import moe_ffn_apply_ep
             return moe_ffn_apply_ep(lp_moe, u, self.cfg, ccfg, pol["mesh"],
                                     batch_axes=pol["batch_axes"])
-        return moe_ffn_apply(lp_moe, u, self.cfg, ccfg, no_drop=no_drop)
+        return moe_ffn_apply(lp_moe, u, self.cfg, ccfg, no_drop=no_drop, routes=routes)
 
     # --------------------------------------------------------------- api
     def _head(self, params, x, ccfg):
@@ -452,7 +531,10 @@ class MoELM(StackedCacheMixin):
         logits = self._head(params, x[:, -1:], ccfg)
         return logits, {"dense_layers": dense_caches, "layers": caches}
 
-    def decode_step(self, params, batch, cache, ccfg):
+    def decode_step(self, params, batch, cache, ccfg, routes: bool = False):
+        """``(logits, cache)``; with ``routes``, ``(logits, cache, routed)``:
+        routed (MoE layers, B, n_experts) bool, which held experts each
+        MoE layer sent each row to (the engine's routed-work counter)."""
         x = L.embed_apply(params["embed"], batch["tokens"])
         bt = batch.get("block_table")
         new_dense = []
@@ -466,12 +548,17 @@ class MoELM(StackedCacheMixin):
             lp, c = scanned
             if bt is not None:
                 c = dict(c, block_table=bt)
-            y, nc = self._block(lp, x, ccfg, c, "decode", moe=True)
-            return y, nc
+            if routes:
+                y, nc, routed = self._block(lp, x, ccfg, c, "decode", moe=True,
+                                            routes=True)
+                return y, (nc, routed)
+            return self._block(lp, x, ccfg, c, "decode", moe=True)
 
-        x, new_caches = lax.scan(body, x, (params["layers"], cache["layers"]))
+        x, ys = lax.scan(body, x, (params["layers"], cache["layers"]))
+        new_caches, routed = ys if routes else (ys, None)
         logits = self._head(params, x, ccfg)
-        return logits, {"dense_layers": new_dense, "layers": new_caches}
+        new_cache = {"dense_layers": new_dense, "layers": new_caches}
+        return (logits, new_cache, routed) if routes else (logits, new_cache)
 
     def prefill_extend(self, params, batch, cache, ccfg, n_valid=None,
                        all_logits: bool = False):

@@ -28,6 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.cascade import CascadeConfig
 from repro.configs.base import ArchConfig
+from repro.models.moe import route
 
 
 def _local_moe(xf, router, *expert_args, cfg: ArchConfig, ccfg: CascadeConfig,
@@ -46,11 +47,7 @@ def _local_moe(xf, router, *expert_args, cfg: ArchConfig, ccfg: CascadeConfig,
     k = cfg.moe_top_k
     rank = lax.axis_index(model_axis)
 
-    logits = jnp.dot(xf.astype(jnp.float32), router)            # (T, E) global
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = lax.top_k(probs, k)                            # (T, k) global ids
-    if cfg.moe_renorm:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    gates, idx = route(xf, router, cfg)                         # (T, k) global ids
 
     flat_e = idx.reshape(-1)                                    # (T*k,)
     local_id = flat_e - rank * e_loc                            # position among local experts

@@ -588,6 +588,11 @@ class ServeEngine:
             else:
                 pin = lambda c_: c_
             self._pin = pin
+            # a model with routed experts returns, beside the logits, which
+            # held experts each row went to (telemetry.ROUTED); a model
+            # without returns nothing extra and its decode program is as is
+            self._routes = bool(getattr(model, "routes_experts", False))
+            rkw = {"routes": True} if self._routes else {}
 
             # paged steps take the host-owned block table as one extra
             # device arg ({"tokens", "block_table"} batch dict); the jitted
@@ -595,9 +600,9 @@ class ServeEngine:
             # per-slot K/V views through the table, so shapes stay fixed
             if self.paged:
                 def _decode_step(p, t, c_, bt):
-                    logits, c2 = model.decode_step(
-                        p, {"tokens": t, "block_table": bt}, c_, ccfg)
-                    return logits, pin(c2)
+                    logits, c2, *routed = model.decode_step(
+                        p, {"tokens": t, "block_table": bt}, c_, ccfg, **rkw)
+                    return (logits, pin(c2), *routed)
 
                 def _extend_step(p, t, c_, bt, n):
                     return model.prefill_extend(
@@ -605,8 +610,9 @@ class ServeEngine:
                         n_valid=n)
             else:
                 def _decode_step(p, t, c_):
-                    logits, c2 = model.decode_step(p, {"tokens": t}, c_, ccfg)
-                    return logits, pin(c2)
+                    logits, c2, *routed = model.decode_step(p, {"tokens": t}, c_,
+                                                             ccfg, **rkw)
+                    return (logits, pin(c2), *routed)
 
                 def _extend_step(p, t, c_, n):
                     return model.prefill_extend(p, {"tokens": t}, c_, ccfg,
@@ -667,16 +673,17 @@ class ServeEngine:
                 # draw fused in one jitted step (no per-step host vocab copy)
                 if self.paged:
                     def _sampled_step(p, t, c_, bt, key):
-                        logits, c2 = model.decode_step(
-                            p, {"tokens": t, "block_table": bt}, c_, ccfg)
-                        return _sample_tokens(logits[:, -1], key,
-                                              scfg.temperature,
-                                              scfg.top_k), pin(c2)
+                        logits, c2, *routed = model.decode_step(
+                            p, {"tokens": t, "block_table": bt}, c_, ccfg, **rkw)
+                        return (_sample_tokens(logits[:, -1], key,
+                                               scfg.temperature,
+                                               scfg.top_k), pin(c2), *routed)
                 else:
                     def _sampled_step(p, t, c_, key):
-                        logits, c2 = model.decode_step(p, {"tokens": t}, c_, ccfg)
-                        return _sample_tokens(logits[:, -1], key, scfg.temperature,
-                                              scfg.top_k), pin(c2)
+                        logits, c2, *routed = model.decode_step(
+                            p, {"tokens": t}, c_, ccfg, **rkw)
+                        return (_sample_tokens(logits[:, -1], key, scfg.temperature,
+                                               scfg.top_k), pin(c2), *routed)
                 self._sample_fn = jax.jit(_sampled_step, donate_argnums=(2,))
         else:
             self._cache_len = scfg.max_len
@@ -1010,18 +1017,23 @@ class ServeEngine:
                                    - req.prompt_carried)
         bt = (self._bt_dev(),) if self.paged else ()
         if self.scfg.temperature <= 0.0:
-            logits, self.cache = self._decode_fn(self.params, jnp.asarray(toks),
-                                                 self.cache, *bt)
+            logits, self.cache, *routed = self._decode_fn(
+                self.params, jnp.asarray(toks), self.cache, *bt)
             picks = jnp.argmax(logits[:, -1], axis=-1)
         else:
             # on-device sampling: one fused decode+categorical dispatch; the
             # per-row Gumbel noise is positional (a function of key + slot
             # index), so active rows never depend on garbage-slot contents
-            picks, self.cache = self._sample_fn(self.params, jnp.asarray(toks),
-                                                self.cache, *bt,
-                                                self._next_sample_key())
+            picks, self.cache, *routed = self._sample_fn(
+                self.params, jnp.asarray(toks), self.cache, *bt,
+                self._next_sample_key())
         with self.telemetry.span(telemetry.DECODE_SYNC):
-            nxt = np.asarray(picks)
+            if routed:      # one transfer: the picks and the routes
+                nxt, routed = jax.device_get((picks, routed))
+            else:
+                nxt = np.asarray(picks)
+        if routed:
+            self._count_routed(routed[0], active)
         produced = 0
         with self.telemetry.span(telemetry.COMMIT):
             for i in active:
@@ -1031,6 +1043,15 @@ class ServeEngine:
                 produced += 1
                 self._retire_if_done(req, i, tok)
         return produced
+
+    def _count_routed(self, routed: np.ndarray, active: List[int]):
+        """``telemetry.ROUTED`` of one decode step from its (MoE layers, B,
+        held experts) routes, over the active rows only."""
+        r = routed[:, active]
+        self.telemetry.count(telemetry.ROUTED, steps=1, rows=len(active),
+                             assignments=int(r.sum()),
+                             pairs=int(r.any(axis=1).sum()),
+                             layer_experts=r.shape[0] * r.shape[2])
 
     def _decode_spec(self, active: List[int]) -> int:
         """One speculative engine step: draft up to K tokens per slot

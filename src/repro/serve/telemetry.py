@@ -24,6 +24,17 @@ registered once per process.
 
 A child span lies inside its parent: a phase's self time is its span less
 its children's. Spans of one request carry its ``uid``.
+
+The routed-work counter ``ROUTED`` (not a span) counts, for each decode step
+of a model with routed experts, the work its held experts were given:
+``steps`` (1 a step), ``rows`` (its active rows), ``assignments`` (token ->
+held-expert assignments of those rows, summed over the MoE layers),
+``pairs`` ((MoE layer, held expert) pairs that got at least one of them)
+and ``layer_experts`` (MoE layers x held experts, the pairs there are). The
+decode step returns which held experts each row went to beside its logits,
+and the engine reads it in the same transfer as the step's picks
+(``DECODE_SYNC``). A model without experts returns nothing extra from its
+decode step and counts nothing here.
 """
 from __future__ import annotations
 
@@ -52,6 +63,8 @@ DECODE_SYNC = "serve.decode_sync"
 COMMIT = "serve.commit"
 #: n-gram drafting of a speculative step
 DRAFT = "serve.draft"
+#: routed work of the held experts in each decode step (not a span)
+ROUTED = "serve.routed"
 #: compilations in the process (not a span)
 COMPILE = "jax.compile"
 
